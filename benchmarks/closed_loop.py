@@ -45,6 +45,7 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro import compile_cache
 from repro.core.recommender import recommend
 from repro.fleet import (ClosedLoopController, FleetConfig, Objective,
                          PIPolicy, SegmentedSimulation, StaticPolicy,
@@ -254,6 +255,7 @@ def main():
     ap.add_argument("--backend", default="auto",
                     choices=("numpy", "jax", "auto"))
     args = ap.parse_args()
+    compile_cache.place()
     res, bench = run(full=args.full, backend=args.backend)
     with open(args.out, "w") as f:
         json.dump(bench, f, indent=2, default=float)

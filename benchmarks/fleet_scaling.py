@@ -34,6 +34,7 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro import compile_cache
 from repro.fleet import (HeterogeneousPredictivePolicy, StaticPolicy,
                          class_table, comparison_table,
                          cost_efficiency_table, default_policies,
@@ -207,6 +208,7 @@ def main():
     ap.add_argument("--out", default="BENCH_fleet.json",
                     help="JSON results path (CI uploads this artifact)")
     args = ap.parse_args()
+    compile_cache.place()
     t0 = time.perf_counter()
     reports, records = run(full=args.full)
     tiered, cheapest = run_tiered(full=args.full)

@@ -12,6 +12,7 @@ import os
 def main():
     os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
     import jax
+    from repro import compile_cache
     from repro.core.cost_model import roofline
     from repro.core.hlo_analysis import analyze_compiled
     from repro.launch.mesh import make_production_mesh
@@ -20,6 +21,7 @@ def main():
     import jax.numpy as jnp
     from functools import partial
 
+    compile_cache.place()
     mesh = make_production_mesh()
     chips = mesh.devices.size
     print("name,us_per_call,derived")

@@ -38,6 +38,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from closed_loop import (ATTAIN_BAR, COLD_BINS, DRIFT_FACTOR, DT_S, SEED,
                          TUNE_BAR, build)
 
+from repro import compile_cache
 from repro.fleet import (ClosedLoopController, Objective, PIPolicy,
                          TuningBudget, TuningScenario, Workload,
                          evaluate_candidates, flash_crowd_trace, tune,
@@ -253,6 +254,7 @@ def main():
     ap.add_argument("--backend", default="auto",
                     choices=("numpy", "jax", "auto"))
     args = ap.parse_args()
+    compile_cache.place()
     table, bench = run(full=args.full, backend=args.backend,
                        table_out=args.table_out)
     with open(args.out, "w") as f:

@@ -30,14 +30,15 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import shutil
 import sys
-import tempfile
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core.recommender import recommend
 from repro.fleet import (FleetConfig, Objective, PredictivePolicy, Trace,
                          TuningBudget, diurnal_trace, evaluate_candidates,
@@ -229,8 +230,8 @@ def run_compile_cache(ts, objective, cache_dir: str):
     """Cold build vs disk-warm rebuild: flush the in-memory jit caches, pay
     XLA compilation once into the persistent cache, flush again, and verify
     the rebuild deserializes from disk (hit counters) with measurably less
-    cold-dispatch wall-clock."""
-    jaxsim.enable_persistent_compile_cache(cache_dir)
+    cold-dispatch wall-clock. The persistent cache must point at an empty
+    ``cache_dir``."""
     cands = PredictivePolicy.param_space().sample_lhs(12, seed=SEED + 7)
 
     def cold_build():
@@ -280,8 +281,17 @@ def run(full: bool = False):
                                numpy_subset=64 if full else 48)
     robustness = run_robustness(ts, objective, budget)
     agreement = run_agreement(ts, objective)
-    with tempfile.TemporaryDirectory(prefix="jaxcache-") as d:
-        cache = run_compile_cache(ts, objective, d)
+    # a fixed subdirectory of the cache's home, emptied so the first build
+    # is cold; the normal placement comes back afterwards. Two runs of this
+    # section must not share one $JAX_COMPILATION_CACHE_DIR at the same time:
+    # one's emptying would turn the other's warm rebuild cold
+    cache_dir = os.path.join(compile_cache.home(), "portfolio-cold-warm")
+    shutil.rmtree(cache_dir, ignore_errors=True)
+    compile_cache.use(cache_dir)
+    try:
+        cache = run_compile_cache(ts, objective, cache_dir)
+    finally:
+        compile_cache.place()
     return {
         "benchmark": "portfolio_tuning",
         "full": full,
@@ -306,6 +316,7 @@ def main():
     ap.add_argument("--out", default="BENCH_portfolio.json",
                     help="JSON results path (CI uploads this artifact)")
     args = ap.parse_args()
+    compile_cache.place()
     bench = run(full=args.full)
     with open(args.out, "w") as f:
         json.dump(bench, f, indent=2)

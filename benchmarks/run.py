@@ -12,6 +12,9 @@ def main() -> None:
     ap.add_argument("--skip-roofline", action="store_true")
     args, _ = ap.parse_known_args()
 
+    from repro import compile_cache
+    compile_cache.place()
+
     print("name,us_per_call,derived")
 
     from benchmarks import (fig4_training_cost, fig5_surveillance_cost,

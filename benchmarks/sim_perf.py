@@ -40,6 +40,7 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+from repro import compile_cache
 from repro.core import get_shape
 from repro.fleet import (Objective, PredictivePolicy, StaticPolicy,
                          evaluate_candidates, simulate, summarize, telemetry,
@@ -368,6 +369,7 @@ def main():
     ap.add_argument("--out", default="BENCH_sim.json",
                     help="JSON results path (CI uploads this artifact)")
     args = ap.parse_args()
+    compile_cache.place()
     bench, tel = run(full=args.full)
     with open(args.out, "w") as f:
         json.dump(bench, f, indent=2)

@@ -28,6 +28,7 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro import compile_cache
 from repro.core.recommender import recommend
 from repro.fleet import (FleetConfig, Objective, PredictivePolicy,
                          StaticPolicy, TuningBudget, evaluate_candidates,
@@ -214,6 +215,7 @@ def main():
                          "family has a kernel — see sim_perf.py; numpy = "
                          "the reference per-candidate loop)")
     args = ap.parse_args()
+    compile_cache.place()
     report, bench = run(full=args.full, backend=args.backend)
     with open(args.out, "w") as f:
         json.dump(bench, f, indent=2)

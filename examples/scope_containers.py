@@ -23,21 +23,28 @@ from repro.mset import estimate, train
 from repro.tpss import TPSSParams, synthesize
 
 
+def mset_workload(params, n_train: int = 1536, n_surveil: int = 512,
+                  impl: str = "auto"):
+    """One scoping cell: TPSS telemetry for ``params["n_signals"]`` signals,
+    MSET2 trained on the first ``n_train`` observations with
+    ``params["n_memvec"]`` memory vectors, then the next ``n_surveil``
+    surveilled. Returns the zero-arg unit of work ``run_measured`` times;
+    it includes ``train``'s eigendecomposition, which runs on the host."""
+    key = jax.random.PRNGKey(params["n_signals"] * 7 + params["n_memvec"])
+    X = synthesize(key, TPSSParams(n_signals=params["n_signals"],
+                                   n_obs=n_train + n_surveil))
+
+    def run():
+        m = train(X[:n_train], n_memvec=params["n_memvec"], impl=impl)
+        return estimate(m, X[n_train:], impl=impl)[1]
+    return run
+
+
 def measured_scoping():
     print("=== 1. nested-loop Monte Carlo scoping (measured, this box) ===")
-
-    def workload(params):
-        key = jax.random.PRNGKey(params["n_signals"] * 7 + params["n_memvec"])
-        X = synthesize(key, TPSSParams(n_signals=params["n_signals"], n_obs=2048))
-
-        def run():
-            m = train(X[:1536], n_memvec=params["n_memvec"])
-            return estimate(m, X[1536:])[1]
-        return run
-
     cs = ContainerStress()
     res = cs.run_measured(
-        workload,
+        mset_workload,
         {"n_signals": [8, 16, 32, 64], "n_memvec": [64, 128, 256, 512]},
         reps=2, constraint=lambda p: p["n_memvec"] >= 2 * p["n_signals"],
         verbose=False)

@@ -8,6 +8,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 import jax
+from jax.sharding import AxisType
 
 from repro.core.cost_model import V5E, HardwareSpec
 
@@ -31,7 +32,13 @@ class CloudShape:
         return self.chips * self.hw.price_per_chip_hour
 
     def make_mesh(self):
-        return jax.make_mesh(self.mesh_shape, self.axes)
+        return auto_mesh(self.mesh_shape, self.axes)
+
+
+def auto_mesh(shape, axes):
+    """A device mesh whose axes GSPMD partitions: ``with_sharding_constraint``
+    accepts only Auto axes, and ``jax.make_mesh`` defaults to Explicit."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 CATALOG: list[CloudShape] = [

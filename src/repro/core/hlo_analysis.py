@@ -95,20 +95,11 @@ class CompiledCost:
         }
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions: older releases
-    return a one-element list of dicts, newer ones a plain dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def analyze_compiled(compiled, n_devices: int = 1,
                      hlo_text: Optional[str] = None) -> CompiledCost:
     """cost_analysis()/memory_analysis() report PER-DEVICE numbers for SPMD
     executables; pass n_devices to globalize."""
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     ma = compiled.memory_analysis()
     text = hlo_text if hlo_text is not None else compiled.as_text()
     colls = parse_collectives(text)

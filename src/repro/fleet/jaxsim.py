@@ -22,14 +22,13 @@ as a pure function of fixed-shape arrays and compiles the whole
   tunable knobs passed as arrays — which is what lets a whole racing round
   (every candidate x every seed) batch into ONE jitted call.
 
-Everything runs in float64 via a scoped ``enable_x64`` so the compiled path
+Everything runs in float64 via a scoped ``jax.enable_x64`` so the compiled path
 agrees with the numpy reference to float rounding; candidate batches are
 padded to power-of-two sizes so racing's shrinking rounds reuse a handful of
 compiled programs instead of recompiling per round.
 """
 from __future__ import annotations
 
-import os
 import time
 
 import numpy as np
@@ -59,8 +58,9 @@ _CORE_CACHE: dict = {}
 _DISPATCHED: set = set()
 
 # Persistent (on-disk) XLA compilation cache bookkeeping: disk hit/miss
-# tallies fed by jax's monitoring events, and the wired cache directory.
-_PCACHE = {"hits": 0, "misses": 0, "dir": None, "listener": False}
+# tallies fed by jax's monitoring events. Where the cache lives is
+# ``repro.compile_cache``'s decision; this module only counts.
+_PCACHE = {"hits": 0, "misses": 0, "listener": False}
 
 _PCACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
                   "/jax/compilation_cache/cache_misses": "miss"}
@@ -74,70 +74,22 @@ def _pcache_event(event: str, **kw) -> None:
     telemetry.counter("jaxsim_compile_cache_disk_total", result=result)
 
 
-def enable_persistent_compile_cache(path: str) -> None:
-    """Wire JAX's on-disk compilation cache through the compiled backend, so
-    repeated tuner rounds, oracle builds and CI runs stop re-paying XLA
-    compilation across *processes*: a cold dispatch whose program was
-    compiled by any earlier run deserializes the executable from ``path``
-    instead of recompiling. The size/compile-time admission floors are
-    dropped (every program persists — fleet cores are small but each costs
-    seconds of XLA time), and a monitoring listener feeds disk hit/miss
-    tallies to ``persistent_cache_stats()`` plus the
+def _attach_cache_listener() -> None:
+    """Feed disk hit/miss tallies to ``persistent_cache_stats()`` and the
     ``jaxsim_compile_cache_disk_total`` telemetry counter (a no-op unless a
     telemetry session is active, so the wiring stays bit-exact)."""
-    import jax
-    for opt, val in (("jax_compilation_cache_dir", str(path)),
-                     ("jax_persistent_cache_min_entry_size_bytes", -1),
-                     ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-        try:
-            jax.config.update(opt, val)
-        except Exception:   # older jax without the admission knobs
-            pass
     if not _PCACHE["listener"]:
-        try:
-            jax.monitoring.register_event_listener(_pcache_event)
-            _PCACHE["listener"] = True
-        except Exception:
-            pass
-    # jax latches "cache in use?" per process at the FIRST compilation
-    # (compilation_cache._cache_checked); enabling after any jit has run
-    # would silently do nothing without a reset back to pristine state.
-    try:
-        from jax._src.compilation_cache import reset_cache
-        reset_cache()
-    except Exception:
-        pass
-    _PCACHE["dir"] = str(path)
-
-
-def disable_persistent_compile_cache() -> None:
-    """Unwire the on-disk compilation cache and restore jax's stock admission
-    floors, returning the process to its pristine no-cache state. Tests that
-    enable the cache against a temporary directory must call this afterwards:
-    the cache config is process-global, and leaving every later jit in the
-    process serializing through a (possibly reaped) tmp dir is both slow and
-    unsafe. Hit/miss tallies are preserved — they are per-process history."""
-    import jax
-    for opt, val in (("jax_compilation_cache_dir", None),
-                     ("jax_persistent_cache_min_entry_size_bytes", 0),
-                     ("jax_persistent_cache_min_compile_time_secs", 1.0)):
-        try:
-            jax.config.update(opt, val)
-        except Exception:
-            pass
-    try:
-        from jax._src.compilation_cache import reset_cache
-        reset_cache()
-    except Exception:
-        pass
-    _PCACHE["dir"] = None
+        import jax
+        jax.monitoring.register_event_listener(_pcache_event)
+        _PCACHE["listener"] = True
 
 
 def persistent_cache_stats() -> dict:
     """Disk-cache tallies since process start: ``{hits, misses, dir}``
-    (``dir`` is None until a cache is wired)."""
+    (``dir`` is None while no persistent cache is in use)."""
+    import jax
     return {"hits": int(_PCACHE["hits"]), "misses": int(_PCACHE["misses"]),
-            "dir": _PCACHE["dir"]}
+            "dir": jax.config.jax_compilation_cache_dir}
 
 
 def clear_compiled() -> list:
@@ -151,11 +103,8 @@ def clear_compiled() -> list:
     evicted = list(_CORE_CACHE.values())
     _CORE_CACHE.clear()
     _DISPATCHED.clear()
-    try:
-        import jax
-        jax.clear_caches()
-    except Exception:
-        pass
+    import jax
+    jax.clear_caches()
     return evicted
 
 
@@ -612,14 +561,8 @@ def run_dynamics(kernel, *, arrivals, jb, dt, order, t_fixed, t_unit, max_b,
     rows are discarded per chunk).
     """
     import jax
-    from jax.experimental import enable_x64
 
-    if _PCACHE["dir"] is None and os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        # env-wired persistent cache (e.g. CI's actions/cache dir): jax reads
-        # the env var itself, but the admission floors and the hit/miss
-        # listener only attach through our wiring
-        enable_persistent_compile_cache(
-            os.environ["JAX_COMPILATION_CACHE_DIR"])
+    _attach_cache_listener()
 
     arrivals = np.asarray(arrivals, np.float64)
     S, T, C = arrivals.shape
@@ -683,7 +626,7 @@ def run_dynamics(kernel, *, arrivals, jb, dt, order, t_fixed, t_unit, max_b,
         attrs.update(tile=_tile_idx[0], n_tiles=_tile_idx[1])
     t0 = time.perf_counter()
     with telemetry.span("jaxsim.dispatch", **attrs):
-        with enable_x64():
+        with jax.enable_x64(True):
             out = core(arrivals, rate, rate_sum, np.asarray(jb, np.int32),
                        pad(tables["cnt"]), pad(tables["cls_of_rank"]),
                        pad(tables["drop_rank"]), pad(tables["key_of_rank"]),

@@ -36,7 +36,7 @@ def _kernel(x_ref, y_ref, x2_ref, y2_ref, o_ref, *, nk: int, gamma: float, kind:
     @pl.when(k == nk - 1)
     def _epilogue():
         acc = o_ref[...]
-        d2 = x2_ref[...][:, None] + y2_ref[...][None, :] - 2.0 * acc
+        d2 = x2_ref[...] + y2_ref[...] - 2.0 * acc   # (bm, 1) + (1, bn)
         d2 = jnp.maximum(d2, 0.0)
         if kind == "inverse_distance":
             o_ref[...] = 1.0 / (1.0 + jnp.sqrt(d2) * (1.0 / gamma))
@@ -51,6 +51,8 @@ def similarity_pallas(x, y, gamma: float = 1.0, kind: str = "inverse_distance",
 
     Shapes are padded to block multiples; padding contributes d2=0 terms that are
     sliced away (norms of zero-padded tails are zero, so distances are exact).
+    The squared norms travel as (mp, 1) and (1, bp) blocks: Mosaic refuses a
+    1-D operand whose XLA layout tiles differently from its own.
     """
     m, n = x.shape
     b, n2 = y.shape
@@ -60,8 +62,8 @@ def similarity_pallas(x, y, gamma: float = 1.0, kind: str = "inverse_distance",
 
     xp = jnp.pad(x, ((0, mp - m), (0, np_ - n)))
     yp = jnp.pad(y, ((0, bp - b), (0, np_ - n)))
-    x2 = jnp.sum(xp.astype(F32) ** 2, axis=-1)
-    y2 = jnp.sum(yp.astype(F32) ** 2, axis=-1)
+    x2 = jnp.sum(xp.astype(F32) ** 2, axis=-1, keepdims=True)     # (mp, 1)
+    y2 = jnp.sum(yp.astype(F32) ** 2, axis=-1)[None, :]           # (1, bp)
 
     nk = np_ // bk_
     grid = (mp // bm_, bp // bn_, nk)
@@ -71,8 +73,8 @@ def similarity_pallas(x, y, gamma: float = 1.0, kind: str = "inverse_distance",
         in_specs=[
             pl.BlockSpec((bm_, bk_), lambda i, j, k: (i, k)),
             pl.BlockSpec((bn_, bk_), lambda i, j, k: (j, k)),
-            pl.BlockSpec((bm_,), lambda i, j, k: (i,)),
-            pl.BlockSpec((bn_,), lambda i, j, k: (j,)),
+            pl.BlockSpec((bm_, 1), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((1, bn_), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, bp), F32),

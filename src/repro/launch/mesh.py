@@ -4,24 +4,26 @@ from __future__ import annotations
 
 import jax
 
+from repro.core.catalog import auto_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_dev_mesh(n_devices: int | None = None):
     """Small mesh over whatever devices exist (tests / CPU runs)."""
     n = n_devices or len(jax.devices())
     if n == 1:
-        return jax.make_mesh((1, 1), ("data", "model"))
+        return auto_mesh((1, 1), ("data", "model"))
     model = 1
     for m in (4, 2):
         if n % m == 0:
             model = m
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return auto_mesh((n // model, model), ("data", "model"))
 
 
 def mesh_axis_sizes(mesh) -> dict:

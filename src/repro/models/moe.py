@@ -16,26 +16,12 @@ Experts are padded to a multiple of the model-axis size when necessary
 """
 from __future__ import annotations
 
-import inspect
 import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-try:                                     # jax >= 0.5 exports it at top level
-    from jax import shard_map as _shard_map
-except ImportError:                      # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-if "check_vma" in inspect.signature(_shard_map).parameters:
-    shard_map = _shard_map
-else:
-    def shard_map(*args, **kw):
-        """Compat shim: older jax calls the replication check ``check_rep``."""
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map(*args, **kw)
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -195,7 +181,7 @@ def _moe_ep(cfg: ArchConfig, p, x, rules: ShardingRules):
     bspec = P(batch_axes if len(batch_axes) != 1 else batch_axes[0], None, None)
     wspec_r = P(None, None)
     wspec_e = P(ep_axis, None, None)                                    # local experts
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(bspec, wspec_r, wspec_e,
                   wspec_e if cfg.mlp_type == "swiglu" else P(), wspec_e),

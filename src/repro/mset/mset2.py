@@ -4,7 +4,7 @@ regression for prognostic surveillance), the paper's pluggable ML workload.
 Training (paper Fig. 4 cost driver):
     D     = memory matrix, (m, n) selected from training data
     G     = D (x) D  — the nonlinear similarity operator (the CUDA/Pallas hot spot)
-    Ginv  = regularized pseudo-inverse of G (eigendecomposition)
+    Ginv  = regularized pseudo-inverse of G (eigendecomposition on the host)
 
 Surveillance (paper Fig. 5 cost driver), streamed over observations x:
     w     = Ginv · (D (x) x)
@@ -19,6 +19,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels.similarity import similarity
 from repro.mset.memory_vectors import build_memory_matrix
@@ -71,11 +72,16 @@ def train(X, n_memvec: int, *, kind: str = "inverse_distance",
     g = float(gamma) if gamma is not None else float(_bandwidth(D))
 
     G = similarity(D, D, gamma=g, kind=kind, impl=impl)          # (m, m)
-    # regularized pseudo-inverse via eigendecomposition (cuSOLVER -> jnp.eigh)
+    # regularized pseudo-inverse via eigendecomposition (the paper's cuSOLVER
+    # step). The eigh runs in float32 LAPACK on the host: a TPU eigh of
+    # thousands of memory vectors compiles for minutes. The product stays on
+    # the device, at full f32 precision as in estimate()
     m = G.shape[0]
-    evals, evecs = jnp.linalg.eigh(G + reg * jnp.eye(m, dtype=F32))
+    evals, evecs = np.linalg.eigh(np.asarray(G, np.float32)
+                                  + np.float32(reg) * np.eye(m, dtype=np.float32))
+    evals, evecs = jnp.asarray(evals), jnp.asarray(evecs)
     inv_evals = jnp.where(evals > reg, 1.0 / evals, 0.0)
-    Ginv = (evecs * inv_evals[None, :]) @ evecs.T
+    Ginv = jnp.matmul(evecs * inv_evals[None, :], evecs.T, precision="highest")
     return MSETModel(D=D, Ginv=Ginv, gamma=g, kind=kind, mean=mean, std=std)
 
 
@@ -84,8 +90,10 @@ def estimate(model: MSETModel, X, impl: str = "auto"):
     """X: (b, n) observations -> (x_hat (b, n), residuals (b, n))."""
     Xs = (X.astype(F32) - model.mean) / model.std
     K = similarity(model.D, Xs, gamma=model.gamma, kind=model.kind, impl=impl)
-    W = model.Ginv @ K                                           # (m, b)
-    Xhat_s = W.T @ model.D                                       # (b, n)
+    # full f32 products: Ginv's eigenvalue inverses reach 1/reg, which the
+    # TPU's default one-pass bf16 matmul does not resolve
+    W = jnp.matmul(model.Ginv, K, precision="highest")           # (m, b)
+    Xhat_s = jnp.matmul(W.T, model.D, precision="highest")       # (b, n)
     Xhat = Xhat_s * model.std + model.mean
     return Xhat, X - Xhat
 

@@ -23,8 +23,8 @@ F32 = jnp.float32
 def _estimate_sharded(D, Ginv, mean, std, X, *, gamma, kind):
     Xs = (X.astype(F32) - mean) / std
     K = similarity_ref(D, Xs, gamma, kind)      # (m, b)
-    W = Ginv @ K                                 # (m, b)
-    Xhat = W.T @ D                               # (b, n)
+    W = jnp.matmul(Ginv, K, precision="highest")     # (m, b), as mset2
+    Xhat = jnp.matmul(W.T, D, precision="highest")   # (b, n)
     Xhat = Xhat * std + mean
     return Xhat, X - Xhat
 

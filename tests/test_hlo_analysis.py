@@ -4,8 +4,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.hlo_analysis import (analyze_compiled, cost_analysis_dict,
-                                     parse_collectives)
+from repro.core.hlo_analysis import analyze_compiled, parse_collectives
 
 
 def test_xla_cpu_counts_loop_body_once():
@@ -27,8 +26,8 @@ def test_xla_cpu_counts_loop_body_once():
             c = jnp.tanh(c @ w)
         return c.sum()
 
-    f_scan = cost_analysis_dict(jax.jit(scanned).lower(x, w).compile())["flops"]
-    f_unroll = cost_analysis_dict(jax.jit(unrolled).lower(x, w).compile())["flops"]
+    f_scan = jax.jit(scanned).lower(x, w).compile().cost_analysis()["flops"]
+    f_unroll = jax.jit(unrolled).lower(x, w).compile().cost_analysis()["flops"]
     assert f_unroll > 8 * f_scan, (f_scan, f_unroll)
 
 

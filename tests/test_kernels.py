@@ -15,7 +15,8 @@ KEY = jax.random.PRNGKey(0)
 # ------------------------------ similarity --------------------------------
 
 @pytest.mark.parametrize("m,b,n", [(64, 32, 16), (256, 256, 256), (130, 70, 33),
-                                   (8, 8, 4), (512, 128, 1024)])
+                                   (8, 8, 4), (512, 128, 1024),
+                                   (300, 100, 20)])   # pads both m and b
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("kind", ["inverse_distance", "gaussian"])
 def test_similarity_kernel_matches_ref(m, b, n, dtype, kind):

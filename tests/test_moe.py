@@ -100,8 +100,7 @@ assert err < 1e-4, err
 
 @pytest.mark.slow
 def test_ep_shard_map_matches_gather_subprocess():
-    env = dict(os.environ, PYTHONPATH="src")
-    env.pop("JAX_PLATFORMS", None)
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", EP_SNIPPET], capture_output=True,
                          text=True, env=env, timeout=600,
                          cwd=os.path.dirname(os.path.dirname(__file__)))

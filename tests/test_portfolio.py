@@ -8,6 +8,7 @@ import os
 import numpy as np
 import pytest
 
+from repro import compile_cache
 from repro.core import CellResult, RooflineTerms, get_shape
 from repro.fleet import (FleetConfig, Objective, OracleGrid, PIPolicy,
                          PoolConfig, StaticPolicy, TuningBudget,
@@ -328,7 +329,7 @@ def test_persistent_compile_cache_disk_hits(tmp_path):
     loads the compiled program from disk (counter-verified hits) and the
     results stay bit-identical."""
     cache = tmp_path / "jaxcache"
-    jaxsim.enable_persistent_compile_cache(str(cache))
+    compile_cache.use(str(cache))
     try:
         svc = _service(t_comp=0.37)  # fresh shape -> fresh compiled core
         tr = poisson_trace(3.0 * svc.max_throughput, 300.0, dt_s=5.0,
@@ -355,7 +356,7 @@ def test_persistent_compile_cache_disk_hits(tmp_path):
     finally:
         # cache config is process-global: later tests in this pytest process
         # must not keep serializing every jit through the reaped tmp dir
-        jaxsim.disable_persistent_compile_cache()
+        compile_cache.use(None)
         jaxsim.clear_compiled()
 
 

@@ -65,7 +65,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, json
 from repro.launch.dryrun import lower_cell
 from repro.core.hlo_analysis import analyze_compiled
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.core.catalog import auto_mesh
+mesh = auto_mesh((2, 4), ("data", "model"))
 from repro.configs import get_config
 from repro.configs import base as cfgbase
 cfgbase.SHAPES["train_4k"] = cfgbase.ShapeSpec("train_4k", "train", 256, 8)
@@ -83,8 +84,7 @@ with mesh:
 @pytest.mark.slow
 @pytest.mark.parametrize("arch", ["minitron-4b", "olmoe-1b-7b", "mamba2-130m"])
 def test_dryrun_8device_subprocess(arch):
-    env = dict(os.environ, PYTHONPATH="src")
-    env.pop("JAX_PLATFORMS", None)
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", DRYRUN_SNIPPET.format(arch=arch)],
                          capture_output=True, text=True, env=env,
                          cwd=os.path.dirname(os.path.dirname(__file__)), timeout=600)
