@@ -34,6 +34,7 @@ import time
 import numpy as np
 
 from repro.fleet import telemetry
+from repro.telemetry import compiles
 
 _EPS = 1e-12
 
@@ -57,38 +58,14 @@ _CORE_CACHE: dict = {}
 # (warm) — the classifier behind the compile-vs-dispatch timing split.
 _DISPATCHED: set = set()
 
-# Persistent (on-disk) XLA compilation cache bookkeeping: disk hit/miss
-# tallies fed by jax's monitoring events. Where the cache lives is
-# ``repro.compile_cache``'s decision; this module only counts.
-_PCACHE = {"hits": 0, "misses": 0, "listener": False}
-
-_PCACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
-                  "/jax/compilation_cache/cache_misses": "miss"}
-
-
-def _pcache_event(event: str, **kw) -> None:
-    result = _PCACHE_EVENTS.get(event)
-    if result is None:
-        return
-    _PCACHE["hits" if result == "hit" else "misses"] += 1
-    telemetry.counter("jaxsim_compile_cache_disk_total", result=result)
-
-
-def _attach_cache_listener() -> None:
-    """Feed disk hit/miss tallies to ``persistent_cache_stats()`` and the
-    ``jaxsim_compile_cache_disk_total`` telemetry counter (a no-op unless a
-    telemetry session is active, so the wiring stays bit-exact)."""
-    if not _PCACHE["listener"]:
-        import jax
-        jax.monitoring.register_event_listener(_pcache_event)
-        _PCACHE["listener"] = True
-
-
 def persistent_cache_stats() -> dict:
-    """Disk-cache tallies since process start: ``{hits, misses, dir}``
-    (``dir`` is None while no persistent cache is in use)."""
+    """Disk-cache tallies since the compile listener was registered (the
+    first dispatch or telemetry session): ``{hits, misses, dir}``; ``dir`` is
+    None while no persistent cache is in use. Where the cache lives is
+    ``repro.compile_cache``'s decision; this only counts."""
     import jax
-    return {"hits": int(_PCACHE["hits"]), "misses": int(_PCACHE["misses"]),
+    t = compiles.tallies()
+    return {"hits": t["cache_hit"][0], "misses": t["cache_miss"][0],
             "dir": jax.config.jax_compilation_cache_dir}
 
 
@@ -562,7 +539,7 @@ def run_dynamics(kernel, *, arrivals, jb, dt, order, t_fixed, t_unit, max_b,
     """
     import jax
 
-    _attach_cache_listener()
+    compiles.listen()
 
     arrivals = np.asarray(arrivals, np.float64)
     S, T, C = arrivals.shape

@@ -207,7 +207,7 @@ def telemetry_dashboard(sim: SimResult, width: int = 60) -> str:
     times), rendered from a throwaway registry — works on any finished
     ``SimResult``, no active telemetry session required."""
     from repro.fleet.telemetry import MetricsRegistry, record_sim
-    from repro.fleet.telemetry.export import dashboard
+    from repro.telemetry.export import dashboard
 
     reg = MetricsRegistry()
     record_sim(reg, sim)

@@ -253,7 +253,7 @@ def _span_to_json(span) -> dict:
 
 
 def _span_from_json(d: dict):
-    from repro.fleet.telemetry.spans import Span
+    from repro.telemetry.spans import Span
     return Span(name=d["name"], attrs=dict(d.get("attrs", {})),
                 duration_s=d.get("duration_s"),
                 children=[_span_from_json(c) for c in d.get("children", [])])

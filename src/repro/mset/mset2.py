@@ -10,17 +10,24 @@ Surveillance (paper Fig. 5 cost driver), streamed over observations x:
     w     = Ginv · (D (x) x)
     x_hat = w^T · D
 residuals x - x_hat feed the SPRT detector (sprt.py).
+
+Under a telemetry session (``repro.telemetry``) ``train`` and ``estimate``
+record host spans: ``mset.train`` with ``.select``, ``.similarity``, ``.d2h``
+(``G`` to the host), ``.eigh``, ``.h2d`` and ``.ginv``; ``mset.estimate``
+around the compiled program. A span times the host: a span that dispatches
+device work closes before that work ends, and the next one that waits for it
+holds the wait. Device time comes from the device trace.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.kernels.similarity import similarity
 from repro.mset.memory_vectors import build_memory_matrix
 
@@ -63,31 +70,38 @@ def train(X, n_memvec: int, *, kind: str = "inverse_distance",
           gamma: Optional[float] = None, reg: float = 1e-6,
           impl: str = "auto") -> MSETModel:
     """X: (n_obs, n_signals) raw training telemetry."""
-    Xf = X.astype(F32)
-    mean = jnp.mean(Xf, axis=0)
-    std = jnp.std(Xf, axis=0) + 1e-6
-    Xs = (Xf - mean) / std
+    with telemetry.span("mset.train", n_memvec=n_memvec, kind=kind):
+        with telemetry.span("mset.train.select"):
+            Xf = X.astype(F32)
+            mean = jnp.mean(Xf, axis=0)
+            std = jnp.std(Xf, axis=0) + 1e-6
+            Xs = (Xf - mean) / std
 
-    D, _ = build_memory_matrix(Xs, n_memvec)
-    g = float(gamma) if gamma is not None else float(_bandwidth(D))
+            D, _ = build_memory_matrix(Xs, n_memvec)
+            g = float(gamma) if gamma is not None else float(_bandwidth(D))
 
-    G = similarity(D, D, gamma=g, kind=kind, impl=impl)          # (m, m)
-    # regularized pseudo-inverse via eigendecomposition (the paper's cuSOLVER
-    # step). The eigh runs in float32 LAPACK on the host: a TPU eigh of
-    # thousands of memory vectors compiles for minutes. The product stays on
-    # the device, at full f32 precision as in estimate()
-    m = G.shape[0]
-    evals, evecs = np.linalg.eigh(np.asarray(G, np.float32)
-                                  + np.float32(reg) * np.eye(m, dtype=np.float32))
-    evals, evecs = jnp.asarray(evals), jnp.asarray(evecs)
-    inv_evals = jnp.where(evals > reg, 1.0 / evals, 0.0)
-    Ginv = jnp.matmul(evecs * inv_evals[None, :], evecs.T, precision="highest")
+        with telemetry.span("mset.train.similarity"):
+            G = similarity(D, D, gamma=g, kind=kind, impl=impl)      # (m, m)
+        # regularized pseudo-inverse via eigendecomposition (the paper's
+        # cuSOLVER step). The eigh runs in float32 LAPACK on the host: a TPU
+        # eigh of thousands of memory vectors compiles for minutes. The
+        # product stays on the device, at full f32 precision as in estimate()
+        m = G.shape[0]
+        with telemetry.span("mset.train.d2h"):
+            G_host = np.asarray(G, np.float32)
+        with telemetry.span("mset.train.eigh"):
+            evals, evecs = np.linalg.eigh(
+                G_host + np.float32(reg) * np.eye(m, dtype=np.float32))
+        with telemetry.span("mset.train.h2d"):
+            evals, evecs = jnp.asarray(evals), jnp.asarray(evecs)
+        with telemetry.span("mset.train.ginv"):
+            inv_evals = jnp.where(evals > reg, 1.0 / evals, 0.0)
+            Ginv = jnp.matmul(evecs * inv_evals[None, :], evecs.T,
+                              precision="highest")
     return MSETModel(D=D, Ginv=Ginv, gamma=g, kind=kind, mean=mean, std=std)
 
 
-@partial(jax.jit, static_argnames=("impl",))
-def estimate(model: MSETModel, X, impl: str = "auto"):
-    """X: (b, n) observations -> (x_hat (b, n), residuals (b, n))."""
+def _estimate(model: MSETModel, X, impl: str = "auto"):
     Xs = (X.astype(F32) - model.mean) / model.std
     K = similarity(model.D, Xs, gamma=model.gamma, kind=model.kind, impl=impl)
     # full f32 products: Ginv's eigenvalue inverses reach 1/reg, which the
@@ -96,6 +110,18 @@ def estimate(model: MSETModel, X, impl: str = "auto"):
     Xhat_s = jnp.matmul(W.T, model.D, precision="highest")       # (b, n)
     Xhat = Xhat_s * model.std + model.mean
     return Xhat, X - Xhat
+
+
+# the compiled program keeps the name jit_estimate in device traces
+_estimate.__name__ = _estimate.__qualname__ = "estimate"
+_estimate_jit = jax.jit(_estimate, static_argnames=("impl",))
+
+
+def estimate(model: MSETModel, X, impl: str = "auto"):
+    """X: (b, n) observations -> (x_hat (b, n), residuals (b, n)). One
+    compiled program; callable inside ``jit``."""
+    with telemetry.span("mset.estimate"):
+        return _estimate_jit(model, X, impl=impl)
 
 
 def surveil(model: MSETModel, X_stream, impl: str = "auto"):

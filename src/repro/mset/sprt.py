@@ -10,6 +10,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import telemetry
+
 F32 = jnp.float32
 
 
@@ -31,30 +33,35 @@ class SPRTParams:
 def sprt(residuals, sigma, p: SPRTParams = SPRTParams(), mu=None):
     """residuals: (T, n); sigma/mu: (n,) residual std/mean from clean validation
     data (mu defaults to 0). Returns (alarms (T, n), llr_pos, llr_neg)."""
-    r = residuals.astype(F32)
-    if mu is not None:
-        r = r - mu[None, :].astype(F32)
-    r = r / sigma[None, :].astype(F32)
-    M = p.m_shift
-    # log-likelihood ratio increments for H1: mean=+M vs H0: mean=0 (unit var)
-    inc_pos = M * r - 0.5 * M * M
-    inc_neg = -M * r - 0.5 * M * M
-    hi, lo = p.upper, p.lower
+    with telemetry.span("mset.sprt"):
+        with telemetry.span("mset.sprt.standardize"):
+            r = residuals.astype(F32)
+            if mu is not None:
+                r = r - mu[None, :].astype(F32)
+            r = r / sigma[None, :].astype(F32)
+            M = p.m_shift
+            # log-likelihood ratio increments for H1: mean=+M vs H0: mean=0
+            # (unit var)
+            inc_pos = M * r - 0.5 * M * M
+            inc_neg = -M * r - 0.5 * M * M
+        with telemetry.span("mset.sprt.thresholds"):
+            hi, lo = p.upper, p.lower
 
-    def step(carry, inc):
-        sp, sn = carry
-        ip, in_ = inc
-        sp = jnp.clip(sp + ip, lo, None)
-        sn = jnp.clip(sn + in_, lo, None)
-        alarm = (sp >= hi) | (sn >= hi)
-        # reset after decision (classic SPRT restart)
-        sp = jnp.where(sp >= hi, 0.0, sp)
-        sn = jnp.where(sn >= hi, 0.0, sn)
-        return (sp, sn), (alarm, sp, sn)
+        def step(carry, inc):
+            sp, sn = carry
+            ip, in_ = inc
+            sp = jnp.clip(sp + ip, lo, None)
+            sn = jnp.clip(sn + in_, lo, None)
+            alarm = (sp >= hi) | (sn >= hi)
+            # reset after decision (classic SPRT restart)
+            sp = jnp.where(sp >= hi, 0.0, sp)
+            sn = jnp.where(sn >= hi, 0.0, sn)
+            return (sp, sn), (alarm, sp, sn)
 
-    n = r.shape[1]
-    z = jnp.zeros(n, F32)
-    _, (alarms, sp, sn) = lax.scan(step, (z, z), (inc_pos, inc_neg))
+        with telemetry.span("mset.sprt.scan"):
+            n = r.shape[1]
+            z = jnp.zeros(n, F32)
+            _, (alarms, sp, sn) = lax.scan(step, (z, z), (inc_pos, inc_neg))
     return alarms, sp, sn
 
 

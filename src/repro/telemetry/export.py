@@ -1,7 +1,7 @@
 """Telemetry exporters: JSONL event log, Prometheus text exposition, and an
 ASCII sparkline dashboard.
 
-All three render the same :class:`~repro.fleet.telemetry.metrics.MetricsRegistry`
+All three render the same :class:`~repro.telemetry.metrics.MetricsRegistry`
 (plus the span tracer and ad-hoc events for JSONL), so a session exports to
 whichever sink fits: JSONL for machine-readable archives (the CI bench job
 uploads one as an artifact), Prometheus text for scrape endpoints, the
@@ -13,7 +13,7 @@ import json
 
 import numpy as np
 
-from repro.fleet.telemetry.metrics import MetricsRegistry
+from repro.telemetry.metrics import MetricsRegistry
 
 # 8-level unicode sparkline ramp (" " for empty bins keeps rows aligned)
 _SPARK = "▁▂▃▄▅▆▇█"
@@ -151,7 +151,7 @@ def dashboard(registry: MetricsRegistry, width: int = 60) -> str:
     compact totals line per counter family and bucket-quantile summaries per
     histogram — the terminal rendering ``repro.fleet.report`` wires into
     fleet reports."""
-    from repro.fleet.telemetry.metrics import label_str
+    from repro.telemetry.metrics import label_str
 
     series, counters, hists = [], {}, []
     for name, labels, m in registry.items():
@@ -189,6 +189,6 @@ def dashboard(registry: MetricsRegistry, width: int = 60) -> str:
 
 
 def _series_label(name: str, labels: dict) -> str:
-    from repro.fleet.telemetry.metrics import label_str
+    from repro.telemetry.metrics import label_str
     ls = label_str(labels)
     return f"{name}{{{ls}}}" if ls else name

@@ -3,9 +3,12 @@
 A span is one timed phase of a larger operation — ``tune`` wraps sampling,
 each racing round, the SPRT culls, and the surface refine; the compiled
 backend wraps every jitted dispatch (tagged cold/warm, which is what splits
-compile-seconds from steady-state dispatch-seconds). Spans nest: entering a
-span inside another parents it, so a completed trace is a tree whose rendered
-form is the timing breakdown ``TuningReport.summary()`` prints.
+compile-seconds from steady-state dispatch-seconds); ``repro.mset`` wraps
+training, estimation and the SPRT. Spans nest: entering a span inside another
+parents it, so a completed trace is a tree whose rendered form is the timing
+breakdown ``TuningReport.summary()`` prints. Phases that are reported only
+once they are over (JAX's tracing, lowering and compilation) are added closed
+with :meth:`SpanTracer.place`.
 
 Unlike the metrics registry (deterministic by construction), spans carry real
 ``time.perf_counter`` durations — they are profiling output, never inputs to
@@ -101,6 +104,24 @@ class SpanTracer:
             s.duration_s = self._clock() - s.t0
             self._stack.pop()
 
+    def place(self, name: str, start: float, end: float, **attrs) -> Span:
+        """Add a phase that is already over, given by ``time.time()`` bounds,
+        under the open span. Closed spans placed there before that lie
+        inside it become its children, so nested phases form a tree."""
+        t0 = start + (self._clock() - time.time())
+        s = Span(name=name, attrs=attrs, t0=t0, duration_s=end - start)
+        siblings = self._stack[-1].children if self._stack else self.roots
+        k = len(siblings)       # siblings come in order of start: scan the tail
+        while k and siblings[k - 1].t0 >= t0:
+            k -= 1
+        keep = []
+        for c in siblings[k:]:
+            within = (c.duration_s is not None
+                      and c.t0 + c.duration_s <= t0 + s.duration_s)
+            (s.children if within else keep).append(c)
+        siblings[k:] = keep + [s]
+        return s
+
     def current(self):
         return self._stack[-1] if self._stack else None
 
@@ -125,6 +146,7 @@ class SpanTracer:
         for root in self.roots:
             for s, depth, path in root.walk():
                 out.append({"type": "span", "name": s.name, "path": path,
-                            "depth": depth, "duration_s": s.duration_s,
+                            "depth": depth, "t0": s.t0,
+                            "duration_s": s.duration_s,
                             **{f"attr_{k}": v for k, v in s.attrs.items()}})
         return out

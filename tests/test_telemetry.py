@@ -417,3 +417,134 @@ def test_degrade_fleet_identity_and_scaling():
         pytest.approx(1.5 * svc.t_per_unit)
     # original untouched (frozen dataclasses are replaced, not mutated)
     assert fleet.pools[0].service.t_fixed == svc.t_fixed
+
+
+# ----------------------- telemetry core: device trace and compile phases ----
+
+def test_spans_annotate_the_device_trace_only_in_a_session(monkeypatch):
+    """No session: a span opens no TraceAnnotation. In a session it opens one
+    of the same name and attributes."""
+    jax = pytest.importorskip("jax")
+    opened = []
+
+    class Recorder:
+        def __init__(self, name, **attrs):
+            opened.append((name, attrs))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    with telemetry.span("mset.sprt", k=1) as s:
+        assert s is None
+    assert opened == []
+    with telemetry.session() as tel:
+        with telemetry.span("mset.sprt", k=1):
+            pass
+    assert opened == [("mset.sprt", {"k": 1})]
+    assert tel.tracer.roots[0].name == "mset.sprt"
+
+
+def test_fresh_jit_counts_one_lowering_and_a_cached_call_none():
+    jax = pytest.importorskip("jax")
+    f = jax.jit(lambda x: x * 3.0 + 1.0)
+    x = np.arange(5.0, dtype=np.float32)
+    with telemetry.session() as tel:
+        f(x)
+    first = tel.metrics.snapshot()["counter"]
+    assert first["jax_compile_events_total"]["phase=lower"] == 1.0
+    assert first["jax_compile_seconds_total"]["phase=lower"] > 0.0
+    lower = [s for s in tel.tracer.roots if s.name == "jit.lower"]
+    assert len(lower) == 1 and lower[0].duration_s > 0.0
+    assert "fun" in lower[0].attrs
+    with telemetry.session() as again:
+        f(x)
+    snap = again.metrics.snapshot()["counter"]
+    assert "phase=lower" not in snap.get("jax_compile_events_total", {})
+
+
+def test_placed_phases_nest_and_take_the_open_span_as_parent():
+    import time
+
+    tr = SpanTracer()
+    now = time.time()
+    with tr.span("mset.sprt.scan"):
+        tr.place("jit.trace", now - 0.004, now - 0.003, fun="inner")
+        tr.place("jit.trace", now - 0.005, now - 0.002, fun="outer")
+        tr.place("jit.lower", now - 0.002, now - 0.001, fun="jit(scan)")
+    scan = tr.roots[0]
+    assert [c.name for c in scan.children] == ["jit.trace", "jit.lower"]
+    outer = scan.children[0]
+    assert outer.attrs["fun"] == "outer"
+    assert [c.attrs["fun"] for c in outer.children] == ["inner"]
+    assert outer.duration_s == pytest.approx(0.003, abs=1e-6)
+    assert scan.t0 - 1.0 < outer.t0 < scan.t0 + 1.0   # on the tracer's clock
+
+
+def test_clock_offset_matches_the_stretch_of_a_longer_session():
+    """The profile saw only the last two of three spans of a name: the
+    offset is still found, and is the median over matched spans."""
+    from repro.telemetry import profile
+
+    clock = iter([1.0, 1.5, 2.0, 2.5, 3.0, 3.5, 4.0, 4.5])
+    tr = SpanTracer(clock=lambda: next(clock))
+    for name in ("mset.sprt", "mset.sprt", "mset.estimate", "mset.sprt"):
+        with tr.span(name):
+            pass
+    off = 7e9
+    events = [("mset.sprt", int(2.0e9 + off), int(2.5e9 + off)),
+              ("mset.estimate", int(3.0e9 + off) + 40, int(3.5e9 + off)),
+              ("mset.sprt", int(4.0e9 + off), int(4.5e9 + off))]
+    assert profile.clock_offset_ns(tr, events) == pytest.approx(off)
+    assert profile.clock_offset_ns(tr, [("other", 0, 1)]) is None
+    placed = profile.placed(tr, off)
+    assert placed[1] == ("mset.sprt", pytest.approx(2.0e9 + off),
+                         pytest.approx(2.5e9 + off))
+
+
+def test_placed_lowering_lies_inside_its_scan_span_on_a_cpu_profile(tmp_path):
+    """On a profile taken here, each ``jit.lower`` placed by the alignment
+    lies inside the ``mset.sprt.scan`` annotation that caused it, to 50 us."""
+    jax = pytest.importorskip("jax")
+    import glob
+
+    import jax.numpy as jnp
+
+    from repro.mset import sprt
+    from repro.telemetry import profile
+
+    r = jax.random.normal(jax.random.PRNGKey(3), (72, 8))
+    sigma = jnp.ones(8)
+    with telemetry.session() as tel:
+        jax.profiler.start_trace(str(tmp_path))
+        for _ in range(3):
+            sprt(r, sigma)
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                         / "*.xplane.pb"))[0]
+    events = profile.host_events(path, ("mset.",))
+    scans = [(s, e) for n, s, e in events if n == "mset.sprt.scan"]
+    assert len(scans) == 3
+    offset = profile.clock_offset_ns(tel.tracer, events)
+    lowers = [(s, e) for p, s, e in profile.placed(tel.tracer, offset)
+              if p.endswith("mset.sprt.scan/jit.lower")]
+    assert len(lowers) == 3          # the scan lowers anew on every call
+    slack = 50_000                   # ns
+    for s, e in lowers:
+        assert any(a - slack <= s and e <= b + slack for a, b in scans)
+
+
+def test_import_mset_imports_nothing_of_the_fleet():
+    import os
+    import subprocess
+    import sys
+
+    code = ("import sys, repro.mset; "
+            "print(sorted(m for m in sys.modules if m.startswith('repro.fleet')))")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, check=True,
+                         env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.stdout.strip() == "[]"
