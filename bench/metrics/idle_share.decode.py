@@ -1,0 +1,6 @@
+"""% of the traced window in which no operation ran on the device."""
+from benchlib.readers import idle_share
+
+
+def read(ctx):
+    return idle_share(ctx)

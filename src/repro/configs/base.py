@@ -44,6 +44,12 @@ class ArchConfig:
     moe_offset: int = 0          # first MoE layer index within a period (jamba: 1)
     moe_d_ff: int = 0            # per-expert hidden size
     capacity_factor: float = 1.25
+    shared_expert_d_ff: int = 0  # one shared expert beside the routed ones (0: none)
+    # expert share (moe_impl "share"): this chip holds experts
+    # [expert_offset, expert_offset + experts_held) of the n_experts the router
+    # scores; 0 holds them all
+    expert_offset: int = 0
+    experts_held: int = 0
 
     # --- SSM (Mamba2 / SSD) ---
     ssm: bool = False
@@ -53,6 +59,7 @@ class ArchConfig:
     ssm_ngroups: int = 1
     conv_width: int = 4
     ssd_chunk: int = 128
+    ssm_norm_eps: float = 1e-6   # eps of the gated RMSNorm rmsnorm(y * silu(z))
 
     # --- hybrid (jamba): attention layer at index `attn_offset` of every
     # `attn_period` layers; all other layers are SSM. ---
@@ -64,6 +71,12 @@ class ArchConfig:
     n_enc_layers: int = 0
     dec_len_fraction: int = 8     # decoder_len = seq_len // this (train/prefill)
     enc_memory_len: int = 4096    # encoder memory length for pure-decode shapes
+
+    # --- scaled paths (granite-4.0-h); the defaults leave a path unscaled ---
+    attn_scale: float = 0.0          # softmax scale of q.k; 0 => 1/sqrt(head_dim)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0  # on each sublayer's output before the add
+    logits_scaling: float = 1.0       # logits are divided by this
 
     # --- modality frontend (stubbed per brief) ---
     frontend: str = "none"       # none | audio | vision
@@ -82,7 +95,8 @@ class ArchConfig:
     # rectangle. §Perf knob 'causal_skip'.
     causal_block_skip: bool = False
     # MoE dispatch implementation: "dense" (one-hot einsum; exact, for small token
-    # counts / smoke) or "ep" (shard_map all-to-all expert parallelism).
+    # counts / smoke), "ep" (shard_map all-to-all expert parallelism) or "share"
+    # (dropless over the held experts, in every mode; see models/moe.py).
     moe_impl: str = "dense"
     # logits softmax accumulation dtype
     softmax_dtype: str = "float32"
@@ -156,8 +170,9 @@ class ArchConfig:
             # FFN
             if self.is_moe_layer(li) and not is_enc:
                 ep = dense_ffn(self.moe_d_ff)
-                layer_t += self.n_experts * ep + d * self.n_experts
-                layer_a += self.n_experts_per_tok * ep + d * self.n_experts
+                shared = dense_ffn(self.shared_expert_d_ff)
+                layer_t += self.n_experts * ep + d * self.n_experts + shared
+                layer_a += self.n_experts_per_tok * ep + d * self.n_experts + shared
             elif self.family == "ssm" or (self.attn_period
                                           and not self.is_attn_layer(li)
                                           and self.d_ff == 0):

@@ -16,6 +16,7 @@ _ARCH_MODULES = {
     "chameleon-34b": "repro.configs.chameleon_34b",
     "mamba2-130m": "repro.configs.mamba2_130m",
     "jamba-v0.1-52b": "repro.configs.jamba_52b",
+    "granite-4.0-h-small": "repro.configs.granite_4h_small",
 }
 
 ARCH_IDS = list(_ARCH_MODULES)
@@ -29,5 +30,5 @@ def get_config(arch_id: str, smoke: bool = False) -> ArchConfig:
 
 
 def all_cells() -> list[tuple[str, str]]:
-    """All 40 (arch x shape) cells, including inapplicable ones (caller filters)."""
+    """Every (arch x shape) cell, inapplicable ones included (caller filters)."""
     return [(a, s) for a in ARCH_IDS for s in SHAPES]

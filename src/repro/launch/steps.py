@@ -110,11 +110,12 @@ class StepBuilder:
 
         return prefill
 
-    def decode_fn(self):
+    def decode_fn(self, greedy: bool = False):
         cfg, rules, model = self.cfg, self.rules, self.model
+        step = model.greedy_step if greedy else model.decode_step
 
         def decode(params, cache, tokens, pos):
-            return model.decode_step(params, cache, tokens, pos, rules)
+            return step(params, cache, tokens, pos, rules)
 
         return decode
 
@@ -152,7 +153,11 @@ class StepBuilder:
             kw = dict(in_shardings=(ps, None), out_shardings=(ps, rep))
         return jax.jit(grad_step, **kw)
 
-    def jit_decode_step(self, shape: ShapeSpec, donate: bool = True):
+    def jit_decode_step(self, shape: ShapeSpec, donate: bool = True,
+                        greedy: bool = False):
+        """The jitted decode step, the cache donated by default. With
+        ``greedy`` it is ``Model.greedy_step``: it also returns the next
+        tokens, picked on the device, and a report for the host."""
         _, cboxed = self.cache_abstract(shape)
         cs = self.cache_shardings(cboxed)
         _, pboxed = self.abstract_params()
@@ -164,8 +169,9 @@ class StepBuilder:
                 ("batch", None, "act_vocab"),
                 (shape.global_batch, 1, self.cfg.vocab_size))
             kw = dict(in_shardings=(ps, cs, None, rep),
-                      out_shardings=(cs, logits_sh))
-        return jax.jit(self.decode_fn(),
+                      out_shardings=(cs, logits_sh) + ((rep, rep) if greedy
+                                                       else ()))
+        return jax.jit(self.decode_fn(greedy),
                        donate_argnums=(1,) if donate else (), **kw)
 
     def jit_prefill(self, shape: ShapeSpec):

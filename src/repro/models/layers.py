@@ -144,7 +144,7 @@ def _sdpa(cfg: ArchConfig, q, k, v, *, causal: bool, q_offset=0, kv_valid_len=No
     kv_valid_len: mask kv positions >= this (decode with pre-allocated cache).
     """
     B, Sq, H, hd = q.shape
-    scale = 1.0 / math.sqrt(hd)
+    scale = cfg.attn_scale or 1.0 / math.sqrt(hd)
 
     if layout == "seq":
         # Decode layout: k/v are (B, K, Skv, hd), SEQ-sharded (the KV cache
@@ -356,7 +356,10 @@ def init_embed(cfg: ArchConfig, key):
 
 
 def embed_tokens(cfg: ArchConfig, p, tokens, rules: ShardingRules):
-    x = jnp.take(p["tok"], tokens, axis=0).astype(jnp.dtype(cfg.dtype))
+    x = jnp.take(p["tok"], tokens, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    x = x.astype(jnp.dtype(cfg.dtype))
     return rules.constrain(x, ("batch", "act_seq", "act_embed"))
 
 
@@ -364,4 +367,6 @@ def unembed(cfg: ArchConfig, p, x, rules: ShardingRules):
     dt = x.dtype
     w = p["tok"].T if cfg.tie_embeddings else p["unembed"]
     logits = jnp.einsum("bsd,dv->bsv", x, w.astype(dt))
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     return rules.constrain(logits, ("batch", "act_seq", "act_vocab"))

@@ -182,7 +182,8 @@ def apply_ssd(cfg: ArchConfig, p, x, rules: ShardingRules, cache=None, pos=None)
         y = jnp.einsum("bhn,bhnp->bhp", Ch, state)
         y = y + p["D"].astype(F32)[None, :, None] * xh
         y = y.reshape(-1, 1, din).astype(dt_m)
-        y = layers.rms_norm_nohead(y * jax.nn.silu(z.astype(F32)).astype(dt_m), p["norm"])
+        y = layers.rms_norm_nohead(y * jax.nn.silu(z.astype(F32)).astype(dt_m), p["norm"],
+                                   cfg.ssm_norm_eps)
         out = jnp.einsum("bse,ed->bsd", y, p["w_out"].astype(dt_m))
         new_cache = {"conv": window[:, 1:, :], "state": state}
         return out, new_cache
@@ -199,7 +200,8 @@ def apply_ssd(cfg: ArchConfig, p, x, rules: ShardingRules, cache=None, pos=None)
     y = y.astype(dt_m)
     y = y + (p["D"].astype(dt_m)[None, None, :, None] * xh)
     y = y.reshape(*x.shape[:2], din)
-    y = layers.rms_norm_nohead(y * jax.nn.silu(z.astype(F32)).astype(dt_m), p["norm"])
+    y = layers.rms_norm_nohead(y * jax.nn.silu(z.astype(F32)).astype(dt_m), p["norm"],
+                               cfg.ssm_norm_eps)
     out = jnp.einsum("bse,ed->bsd", y, p["w_out"].astype(dt_m))
 
     new_cache = None

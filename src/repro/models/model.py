@@ -41,6 +41,9 @@ class Model:
     def decode_step(self, params, cache, tokens, pos, rules: ShardingRules, **kw):
         return transformer.decode_step(self.cfg, params, cache, tokens, pos, rules, **kw)
 
+    def greedy_step(self, params, cache, tokens, pos, rules: ShardingRules, **kw):
+        return transformer.greedy_step(self.cfg, params, cache, tokens, pos, rules, **kw)
+
     def cache_specs(self, batch: int, max_seq: int):
         return transformer.cache_specs(self.cfg, batch, max_seq)
 

@@ -1,6 +1,7 @@
 """Mixture-of-Experts FFN.
 
-Two dispatch implementations, both FLOP-faithful (no dense all-experts compute):
+Three dispatch implementations; the first two are FLOP-faithful (no dense
+all-experts compute):
 
 * ``gather`` — global sort-free grouped dispatch via cumsum-ranked scatter into an
   (E, C) buffer. Exact when capacity suffices; used for decode (few tokens) and
@@ -10,9 +11,18 @@ Two dispatch implementations, both FLOP-faithful (no dense all-experts compute):
   exchanged with ``all_to_all`` to the expert-owner shards, computed, and returned.
   This is the production path for train/prefill and makes the MoE collective
   schedule (2× all_to_all + all_gather) explicit in the HLO.
+* ``share`` — one chip's expert share (``cfg.moe_impl == "share"``, in every
+  mode): the layer holds experts [a, a + n) of the ``n_experts`` its router
+  scores, routes over all of them, and computes only its own experts' part of
+  the result. Dropless: each held expert takes every token at its gate, which
+  is 0 where the router did not pick it, so no capacity bounds it. At decode
+  batch sizes that costs what grouping would (a token picks up to k of the
+  held experts); in prefill it computes held / (k x held / n_experts) times
+  the flops a grouped kernel would.
 
 Experts are padded to a multiple of the model-axis size when necessary
-(granite-moe: 40 -> 48); the router never selects padded experts.
+(granite-moe: 40 -> 48); the router never selects padded experts. A shared
+expert (``cfg.shared_expert_d_ff``) is added once, whatever the dispatch.
 """
 from __future__ import annotations
 
@@ -38,35 +48,56 @@ def padded_experts(cfg: ArchConfig, ep_size: Optional[int]) -> int:
     return e
 
 
+def held_experts(cfg: ArchConfig) -> tuple[int, int]:
+    """(first, count) of the experts an expert-share layer holds."""
+    return cfg.expert_offset, cfg.experts_held or cfg.n_experts
+
+
 def init_moe(cfg: ArchConfig, key, ep_size: Optional[int] = None):
     d, ff = cfg.d_model, cfg.moe_d_ff
-    e_pad = padded_experts(cfg, ep_size)
+    # an expert share draws each expert at its own fan-in, so that the
+    # weights do not depend on how many experts a chip holds
+    if cfg.moe_impl == "share":
+        e_pad = held_experts(cfg)[1]
+        scale = math.sqrt(e_pad)
+    else:
+        e_pad, scale = padded_experts(cfg, ep_size), 1.0
     ks = jax.random.split(key, 4)
     p = {
         "router": layers.dense_init(ks[0], (d, cfg.n_experts), ("embed", None)),
         "w_up": layers.dense_init(ks[1], (e_pad, d, ff),
-                                  ("experts", "embed", "expert_mlp"), in_axis=1),
+                                  ("experts", "embed", "expert_mlp"), in_axis=1,
+                                  scale=scale),
         "w_down": layers.dense_init(ks[2], (e_pad, ff, d),
-                                    ("experts", "expert_mlp", "embed"), in_axis=1),
+                                    ("experts", "expert_mlp", "embed"), in_axis=1,
+                                    scale=scale),
     }
     if cfg.mlp_type == "swiglu":
         p["w_gate"] = layers.dense_init(ks[3], (e_pad, d, ff),
-                                        ("experts", "embed", "expert_mlp"), in_axis=1)
+                                        ("experts", "embed", "expert_mlp"), in_axis=1,
+                                        scale=scale)
+    if cfg.shared_expert_d_ff:
+        p["shared"] = layers.init_mlp(cfg, jax.random.fold_in(key, 1),
+                                      cfg.shared_expert_d_ff)
     return p
+
+
+def _act(cfg: ArchConfig, h, g):
+    if cfg.mlp_type == "swiglu":
+        return jax.nn.silu(g) * h
+    if cfg.mlp_type == "relu2":
+        return jnp.square(jax.nn.relu(h))
+    return jax.nn.gelu(h)
 
 
 def _expert_ffn(cfg: ArchConfig, p, xg):
     """xg: (E, C, d) -> (E, C, d) through per-expert FFN."""
     dt = xg.dtype
     h = jnp.einsum("ecd,edf->ecf", xg, p["w_up"].astype(dt))
+    g = None
     if cfg.mlp_type == "swiglu":
         g = jnp.einsum("ecd,edf->ecf", xg, p["w_gate"].astype(dt))
-        h = jax.nn.silu(g) * h
-    elif cfg.mlp_type == "relu2":
-        h = jnp.square(jax.nn.relu(h))
-    else:
-        h = jax.nn.gelu(h)
-    return jnp.einsum("ecf,efd->ecd", h, p["w_down"].astype(dt))
+    return jnp.einsum("ecf,efd->ecd", _act(cfg, h, g), p["w_down"].astype(dt))
 
 
 def _route(cfg: ArchConfig, logits):
@@ -195,8 +226,43 @@ def _moe_ep(cfg: ArchConfig, p, x, rules: ShardingRules):
     return y, aux
 
 
+def _moe_share(cfg: ArchConfig, p, x, rules: ShardingRules):
+    """The held experts' part of the layer, dropless. x: (B, S, d).
+    Returns (y, aux, held): held counts the (token, expert) assignments the
+    router gave to experts this layer holds."""
+    B, S, d = x.shape
+    T = B * S
+    xf = x.reshape(T, d)
+    logits = jnp.einsum("td,de->te", xf, p["router"].astype(x.dtype))
+    top_i, top_w, aux = _route(cfg, logits)
+    lo, n = held_experts(cfg)
+    mine = jax.nn.one_hot(top_i - lo, n, dtype=top_w.dtype)           # (T, k, n)
+    gate = jnp.sum(mine * top_w[..., None], axis=1)                   # (T, n)
+    held = jnp.sum((top_i >= lo) & (top_i < lo + n), dtype=jnp.int32)
+    dt = xf.dtype
+    h = jnp.einsum("td,edf->etf", xf, p["w_up"].astype(dt))
+    g = None
+    if cfg.mlp_type == "swiglu":
+        g = jnp.einsum("td,edf->etf", xf, p["w_gate"].astype(dt))
+    h = _act(cfg, h, g) * gate.T[..., None].astype(dt)
+    h = rules.constrain(h, ("experts", None, "expert_mlp"))
+    y = jnp.einsum("etf,efd->td", h, p["w_down"].astype(dt))
+    return y.reshape(B, S, d), aux, held
+
+
 def apply_moe(cfg: ArchConfig, p, x, rules: ShardingRules, impl: Optional[str] = None):
-    impl = impl or cfg.moe_impl
-    if impl == "ep" and rules.mesh is not None:
-        return _moe_ep(cfg, p, x, rules)
-    return _moe_gather(cfg, p, x, rules)
+    """Returns (y, aux, held): the layer's output with the shared expert's
+    added, the load-balancing loss, and the (token, expert) assignments that
+    went to experts held here (all of them, but for an expert share)."""
+    if cfg.moe_impl == "share":
+        y, aux, held = _moe_share(cfg, p, x, rules)
+    else:
+        impl = impl or cfg.moe_impl
+        if impl == "ep" and rules.mesh is not None:
+            y, aux = _moe_ep(cfg, p, x, rules)
+        else:
+            y, aux = _moe_gather(cfg, p, x, rules)
+        held = jnp.int32(x.shape[0] * x.shape[1] * cfg.n_experts_per_tok)
+    if "shared" in p:
+        y = y + layers.apply_mlp(cfg, p["shared"], x, rules)
+    return y, aux, held

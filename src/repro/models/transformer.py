@@ -127,8 +127,8 @@ def _apply_block_pos(cfg: ArchConfig, entry: dict, p, x, rules: ShardingRules, *
                      mode: str, positions, cache_entry=None, pos=None,
                      enc_out=None, moe_impl=None, q_chunk=1024):
     """One sublayer-group. mode: 'train' | 'prefill' | 'decode'.
-    Returns (x, new_cache_entry, aux)."""
-    aux = jnp.zeros((), F32)
+    Returns (x, new_cache_entry, stats): stats as ``_no_stats`` gives them."""
+    stats = _no_stats()
     new_cache: dict[str, Any] = {}
 
     h = layers.apply_norm(cfg, p["norm1"], x)
@@ -154,7 +154,7 @@ def _apply_block_pos(cfg: ArchConfig, entry: dict, p, x, rules: ShardingRules, *
                                       cache=({} if mode == "prefill" else None))
             if mode == "prefill":
                 new_cache["ssm"] = nc
-    x = x + out
+    x = x + _residual(cfg, out)
     x = rules.constrain(x, ("batch", "act_seq", "act_embed"))
 
     if entry["cross"]:
@@ -176,10 +176,24 @@ def _apply_block_pos(cfg: ArchConfig, entry: dict, p, x, rules: ShardingRules, *
             out = layers.apply_mlp(cfg, p["ffn"], h, rules)
         else:
             impl = moe_impl or ("gather" if mode == "decode" else cfg.moe_impl)
-            out, aux = moe.apply_moe(cfg, p["ffn"], h, rules, impl=impl)
-        x = x + out
+            out, aux, held = moe.apply_moe(cfg, p["ffn"], h, rules, impl=impl)
+            stats = {"moe_aux": aux, "moe_held": held}
+        x = x + _residual(cfg, out)
         x = rules.constrain(x, ("batch", "act_seq", "act_embed"))
-    return x, new_cache, aux
+    return x, new_cache, stats
+
+
+def _no_stats() -> dict:
+    """What a sublayer-group reports: the MoE load-balancing loss and the
+    (token, expert) assignments to experts held here, summed over layers."""
+    return {"moe_aux": jnp.zeros((), F32), "moe_held": jnp.zeros((), jnp.int32)}
+
+
+def _residual(cfg: ArchConfig, out):
+    """A sublayer's output as it is added to the residual stream."""
+    if cfg.residual_multiplier != 1.0:
+        out = out * jnp.asarray(cfg.residual_multiplier, out.dtype)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -189,7 +203,8 @@ def _apply_block_pos(cfg: ArchConfig, entry: dict, p, x, rules: ShardingRules, *
 def _run_stack(cfg: ArchConfig, blocks, x, rules: ShardingRules, *, mode: str,
                positions, prog, cache=None, pos=None, enc_out=None,
                moe_impl=None, q_chunk=1024, remat: bool = False):
-    """Scan the stacked blocks. Returns (x, new_cache_or_None, aux_sum)."""
+    """Scan the stacked blocks. Returns (x, new_cache_or_None, stats summed
+    over the layers)."""
 
     def body(carry, xs):
         xc = carry
@@ -198,16 +213,16 @@ def _run_stack(cfg: ArchConfig, blocks, x, rules: ShardingRules, *, mode: str,
         else:
             layer_ps, cache_in = xs, None
         new_caches = []
-        aux_total = jnp.zeros((), F32)
+        total = _no_stats()
         for j, entry in enumerate(prog):
             ce = cache_in[j] if cache_in is not None else None
-            xc, nc, aux = _apply_block_pos(
+            xc, nc, stats = _apply_block_pos(
                 cfg, entry, layer_ps[j], xc, rules, mode=mode, positions=positions,
                 cache_entry=ce, pos=pos, enc_out=enc_out, moe_impl=moe_impl,
                 q_chunk=q_chunk)
             new_caches.append(nc)
-            aux_total = aux_total + aux
-        out_ys = (tuple(new_caches), aux_total) if mode != "train" else aux_total
+            total = jax.tree.map(jnp.add, total, stats)
+        out_ys = (tuple(new_caches), total) if mode != "train" else total
         return xc, out_ys
 
     if remat:
@@ -225,9 +240,9 @@ def _run_stack(cfg: ArchConfig, blocks, x, rules: ShardingRules, *, mode: str,
     else:
         x, ys = lax.scan(body, x, xs)
     if mode == "train":
-        return x, None, jnp.sum(ys)
-    new_cache, auxs = ys
-    return x, new_cache, jnp.sum(auxs)
+        return x, None, jax.tree.map(jnp.sum, ys)
+    new_cache, stats = ys
+    return x, new_cache, jax.tree.map(jnp.sum, stats)
 
 
 # ---------------------------------------------------------------------------
@@ -265,12 +280,12 @@ def forward_train(cfg: ArchConfig, params, batch, rules: ShardingRules,
     if cfg.pos_emb == "sinusoidal":
         x = x + layers.sinusoidal_pos_emb(positions, cfg.d_model, x.dtype)[None]
     prog = block_program(cfg)
-    x, _, aux = _run_stack(cfg, params["blocks"], x, rules, mode="train",
-                           positions=positions, prog=prog, enc_out=enc_out,
-                           moe_impl=moe_impl, q_chunk=q_chunk, remat=remat)
+    x, _, stats = _run_stack(cfg, params["blocks"], x, rules, mode="train",
+                             positions=positions, prog=prog, enc_out=enc_out,
+                             moe_impl=moe_impl, q_chunk=q_chunk, remat=remat)
     x = layers.apply_norm(cfg, params["final_norm"], x)
     logits = layers.unembed(cfg, params["embed"], x, rules)
-    return logits, aux
+    return logits, stats["moe_aux"]
 
 
 def loss_fn(cfg: ArchConfig, params, batch, rules: ShardingRules,
@@ -318,21 +333,49 @@ def forward_prefill(cfg: ArchConfig, params, batch, rules: ShardingRules,
     return new_cache, logits
 
 
-def decode_step(cfg: ArchConfig, params, cache, tokens, pos, rules: ShardingRules,
-                moe_impl="gather"):
-    """One decode step. tokens: (B, 1); pos: scalar absolute position.
-    Returns (new_cache, logits (B, 1, V))."""
+def _decode(cfg: ArchConfig, params, cache, tokens, pos, rules, moe_impl):
     x = layers.embed_tokens(cfg, params["embed"], tokens, rules)
     if cfg.pos_emb == "sinusoidal":
         pe = layers.sinusoidal_pos_emb(jnp.asarray(pos)[None], cfg.d_model, x.dtype)
         x = x + pe[None]
     prog = block_program(cfg)
-    x, new_cache, _ = _run_stack(cfg, params["blocks"], x, rules, mode="decode",
-                                 positions=None, prog=prog, cache=cache, pos=pos,
-                                 moe_impl=moe_impl)
+    x, new_cache, stats = _run_stack(cfg, params["blocks"], x, rules, mode="decode",
+                                     positions=None, prog=prog, cache=cache, pos=pos,
+                                     moe_impl=moe_impl)
     x = layers.apply_norm(cfg, params["final_norm"], x)
     logits = layers.unembed(cfg, params["embed"], x, rules)
+    return new_cache, logits, stats
+
+
+def decode_step(cfg: ArchConfig, params, cache, tokens, pos, rules: ShardingRules,
+                moe_impl="gather"):
+    """One decode step. tokens: (B, 1); pos: scalar absolute position.
+    Returns (new_cache, logits (B, 1, V))."""
+    new_cache, logits, _ = _decode(cfg, params, cache, tokens, pos, rules, moe_impl)
     return new_cache, logits
+
+
+def greedy_step(cfg: ArchConfig, params, cache, tokens, pos, rules: ShardingRules,
+                moe_impl="gather"):
+    """One decode step that also picks each sequence's next token on the device.
+
+    Returns (new_cache, logits (B, 1, V), next_tokens (B, 1) int32, report):
+    ``report`` is int32 (B + 1,), the next tokens and then the step's
+    (token, expert) assignments to experts held here, so that one copy to
+    the host brings both (``split_report``)."""
+    new_cache, logits, stats = _decode(cfg, params, cache, tokens, pos, rules,
+                                       moe_impl)
+    nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+    report = jnp.concatenate([nxt, stats["moe_held"][None]])
+    return new_cache, logits, nxt[:, None], report
+
+
+def split_report(cfg: ArchConfig, report) -> tuple:
+    """(next token ids, assignments to held experts, to absent experts) of
+    one host copy of ``greedy_step``'s report."""
+    ids, held = report[:-1], int(report[-1])
+    n_moe = sum(cfg.is_moe_layer(i) for i in range(cfg.n_layers))
+    return ids, held, len(ids) * cfg.n_experts_per_tok * n_moe - held
 
 
 # ---------------------------------------------------------------------------
