@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.mset import SPRTParams, empirical_false_alarm_rate, sprt
+from repro.mset.sprt import SPRT_BLOCK
 
 
 def test_false_alarm_rate_on_clean_noise():
@@ -81,13 +82,15 @@ def _sprt_loop(r, sigma, mu, p):
     return alarms, sps, sns
 
 
-def test_compiled_sprt_equals_the_float32_loop_on_a_ramp():
-    r, sigma, mu = _ramp_residuals()
+@pytest.mark.parametrize("T", [512, 509, SPRT_BLOCK - 1, 3 * SPRT_BLOCK + 1])
+def test_compiled_sprt_equals_the_float32_loop_on_a_ramp(T):
+    """Whole blocks, a tail after them, a tail alone: every T is exact."""
+    r, sigma, mu = _ramp_residuals(T=T)
     p = SPRTParams()
     alarms, sp, sn = sprt(jnp.asarray(r), jnp.asarray(sigma), p,
                           mu=jnp.asarray(mu))
     want_a, want_sp, want_sn = _sprt_loop(r, sigma, mu, p)
-    assert np.asarray(alarms)[256:, 5].any()          # the ramp is caught
+    assert np.asarray(alarms)[T // 2:, 5].any()       # the ramp is caught
     np.testing.assert_array_equal(np.asarray(alarms), want_a)
     # the compiler may fuse M*z - M*M/2 into one multiply-add (XLA:CPU does),
     # one rounding fewer per increment; the state sums a few such differences
@@ -95,6 +98,32 @@ def test_compiled_sprt_equals_the_float32_loop_on_a_ramp():
     atol = 32 * np.finfo(np.float32).eps * p.upper
     np.testing.assert_allclose(np.asarray(sp), want_sp, rtol=0, atol=atol)
     np.testing.assert_allclose(np.asarray(sn), want_sn, rtol=0, atol=atol)
+
+
+def test_the_compiled_loop_runs_a_block_of_rows_an_iteration():
+    import re
+
+    from repro.mset.sprt import _sprt_jit
+
+    r = jax.ShapeDtypeStruct((512, 64), jnp.float32)
+    v = jax.ShapeDtypeStruct((64,), jnp.float32)
+    hlo = _sprt_jit.lower(r, v, v, p=SPRTParams()).compile().as_text()
+    trips = re.findall(r'known_trip_count":\{"n":"(\d+)"', hlo)
+    assert trips == [str(512 // SPRT_BLOCK)]
+
+
+@pytest.mark.parametrize("T", [512, 509])
+def test_rows_counter_splits_blocks_and_tail(T):
+    from repro import telemetry
+
+    r, sigma, mu = (jnp.asarray(a) for a in _ramp_residuals(T=T, n=8))
+    with telemetry.session() as tel:
+        sprt(r, sigma, mu=mu)
+        sprt(r, sigma, mu=mu)
+    rows = tel.metrics.snapshot()["counter"]["mset_sprt_rows_total"]
+    tail = T % SPRT_BLOCK
+    assert (T == 509) == (tail > 0)                   # 509 leaves a tail
+    assert rows == {"part=block": 2.0 * (T - tail), "part=tail": 2.0 * tail}
 
 
 def test_warm_calls_lower_nothing():

@@ -1,6 +1,6 @@
 """Compiles of the main path's device programs for one described TPU v5e chip:
-the MSET similarity kernel, the flash-attention kernel and the fleet simulator
-core, each at a real width. Nothing runs; the chip's compiler refuses here what
+the MSET similarity kernel and SPRT, the flash-attention kernel and the fleet
+simulator core, each at a real width. Nothing runs; the chip's compiler refuses here what
 it would refuse on the chip (interpret mode cannot show that).
 
 The topology is described inside a fixture, never at import: only one process
@@ -44,6 +44,21 @@ def test_similarity_kernel_compiles(one_chip, m, n, b):
     y = _spec((b, n), jnp.float32, one_chip)
     compiled = jax.jit(similarity_pallas).lower(x, y).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("T", [512, 509])             # whole blocks; a tail
+def test_sprt_compiles_to_one_blocked_loop(one_chip, T):
+    import re
+
+    from repro.mset import SPRTParams
+    from repro.mset.sprt import SPRT_BLOCK, _sprt_jit
+
+    r = _spec((T, 1024), jnp.float32, one_chip)
+    v = _spec((1024,), jnp.float32, one_chip)
+    hlo = _sprt_jit.lower(r, v, v, p=SPRTParams()).compile().as_text()
+    # the loop that carries the (T, 1024) alarms, built a block at a time
+    assert re.search(rf"%while[\w.-]* = \(.*?\bpred\[{T},1024\]", hlo)
+    assert re.search(rf"= pred\[{SPRT_BLOCK},1024\]", hlo)
 
 
 def test_flash_attention_compiles(one_chip):
