@@ -69,7 +69,6 @@ class ArchConfig:
     # --- encoder-decoder ---
     encdec: bool = False
     n_enc_layers: int = 0
-    dec_len_fraction: int = 8     # decoder_len = seq_len // this (train/prefill)
     enc_memory_len: int = 4096    # encoder memory length for pure-decode shapes
 
     # --- scaled paths (granite-4.0-h); the defaults leave a path unscaled ---
@@ -78,26 +77,20 @@ class ArchConfig:
     residual_multiplier: float = 1.0  # on each sublayer's output before the add
     logits_scaling: float = 1.0       # logits are divided by this
 
-    # --- modality frontend (stubbed per brief) ---
-    frontend: str = "none"       # none | audio | vision
-
     # --- numerics / perf knobs ---
     dtype: str = "bfloat16"
     remat: str = "full"          # full | none  (activation checkpointing per layer)
-    use_flash_kernel: bool = False   # Pallas attention on real TPU
-    scan_layers: bool = True
     # unroll all internal loops (layer stack, q-chunks). Used by the dry-run cost
     # probes: XLA:CPU cost_analysis counts while-loop bodies ONCE, so accurate
-    # FLOP/byte extraction needs loop-free HLO (see DESIGN.md §Dry-run).
+    # FLOP/byte extraction needs loop-free HLO (tests/test_hlo_analysis.py).
     unroll: bool = False
     # block-causal attention: q-chunk i attends only kv[0:(i+1)*Qc] (static
     # slices, unrolled q loop) — halves attention FLOPs+bytes vs the full
     # rectangle. §Perf knob 'causal_skip'.
     causal_block_skip: bool = False
-    # MoE dispatch implementation: "dense" (one-hot einsum; exact, for small token
-    # counts / smoke), "ep" (shard_map all-to-all expert parallelism) or "share"
-    # (dropless over the held experts, in every mode; see models/moe.py).
-    moe_impl: str = "dense"
+    # MoE implementation: "gather" (capacity-bounded), "ep" (shard_map expert
+    # parallelism) or "share" (dropless); moe.dispatch picks what a layer runs.
+    moe_impl: str = "gather"
     # logits softmax accumulation dtype
     softmax_dtype: str = "float32"
 
@@ -162,8 +155,6 @@ class ArchConfig:
             else:
                 layer_t += attn_params()
                 layer_a += attn_params()
-                if is_enc:
-                    pass
                 if (not is_enc) and self.encdec:
                     layer_t += attn_params()               # cross-attention
                     layer_a += attn_params()
@@ -208,7 +199,8 @@ SHAPES: dict[str, ShapeSpec] = {
 def shape_applicable(cfg: ArchConfig, shape: ShapeSpec) -> tuple[bool, str]:
     """long_500k needs sub-quadratic sequence handling => SSM/hybrid only."""
     if shape.name == "long_500k" and cfg.family not in ("ssm", "hybrid"):
-        return False, "long_500k skipped: pure full-attention arch (see DESIGN.md)"
+        return False, ("long_500k skipped: pure full-attention arch (only SSM "
+                       "and hybrid archs run it)")
     return True, ""
 
 
@@ -226,16 +218,16 @@ def input_specs(cfg: ArchConfig, shape: ShapeSpec) -> dict[str, Any]:
     train  -> {tokens, targets[, frames]}          (token ids / stub embeddings)
     prefill-> {tokens[, frames]}
     decode -> {tokens (B,1), pos ()}  (+ cache specs are produced by the model)
+
+    An encoder-decoder's encoder takes precomputed frame embeddings (B, S,
+    d_model), the stub of its modality frontend.
     """
     B, S = shape.global_batch, shape.seq_len
     specs: dict[str, Any] = {}
     if cfg.encdec:
-        dec_len = max(S // cfg.dec_len_fraction, 16)
+        dec_len = decoder_len(S)
         if shape.kind in ("train", "prefill"):
-            if cfg.frontend == "audio":
-                specs["frames"] = _sds((B, S, cfg.d_model), cfg.dtype)
-            else:
-                specs["src_tokens"] = _sds((B, S), "int32")
+            specs["frames"] = _sds((B, S, cfg.d_model), cfg.dtype)
             specs["tokens"] = _sds((B, dec_len), "int32")
             if shape.kind == "train":
                 specs["targets"] = _sds((B, dec_len), "int32")
@@ -255,6 +247,12 @@ def input_specs(cfg: ArchConfig, shape: ShapeSpec) -> dict[str, Any]:
     return specs
 
 
+def decoder_len(seq_len: int) -> int:
+    """An encoder-decoder's decoder length for a train/prefill shape of
+    ``seq_len`` encoder positions."""
+    return max(seq_len // 8, 16)
+
+
 def model_flops(cfg: ArchConfig, shape: ShapeSpec) -> float:
     """MODEL_FLOPS = 6·N·D (dense) / 6·N_active·D (MoE), D = processed tokens.
 
@@ -264,18 +262,11 @@ def model_flops(cfg: ArchConfig, shape: ShapeSpec) -> float:
     """
     counts = cfg.param_counts()
     n = counts["active"]
-    if shape.kind == "train":
+    if shape.kind in ("train", "prefill"):
         toks = shape.tokens
         if cfg.encdec:
-            toks = shape.global_batch * (
-                shape.seq_len + max(shape.seq_len // cfg.dec_len_fraction, 16))
-        return 6.0 * n * toks
-    if shape.kind == "prefill":
-        toks = shape.tokens
-        if cfg.encdec:
-            toks = shape.global_batch * (
-                shape.seq_len + max(shape.seq_len // cfg.dec_len_fraction, 16))
-        return 2.0 * n * toks
+            toks = shape.global_batch * (shape.seq_len + decoder_len(shape.seq_len))
+        return (6.0 if shape.kind == "train" else 2.0) * n * toks
     # decode: one token per sequence; params touched = active (non-embedding lookup
     # cost dominated by matmuls) — keep the simple 2·N·B convention.
     return 2.0 * n * shape.global_batch

@@ -1,5 +1,6 @@
 """chameleon-34b — early-fusion VLM; image VQ tokens share the 65536 vocab, so the
-backbone consumes plain token ids (VQ tokenizer stubbed). qk-norm. [arXiv:2405.09818]
+backbone consumes plain token ids. There is no vision frontend: the VQ image
+tokenizer is a stub, and inputs are token ids already. qk-norm. [arXiv:2405.09818]
 """
 from repro.configs.base import ArchConfig
 
@@ -17,7 +18,6 @@ CONFIG = ArchConfig(
     norm="rmsnorm",
     pos_emb="rope",
     qk_norm=True,
-    frontend="vision",
 )
 
 SMOKE = CONFIG.replace(

@@ -2,8 +2,9 @@
 
 Note: the assignment line says "llama-arch"; with a 3-matmul SwiGLU MLP the listed
 dims give 28B params, but granite-20b-code is a 20B gpt-bigcode-style model with a
-2-matmul GELU MLP. We keep RoPE+RMSNorm (llama-style) and use the GELU MLP so the
-parameter count matches the published 20B (see DESIGN.md §Arch-applicability).
+2-matmul GELU MLP. We use the GELU MLP so the parameter count matches the
+published 20B, and depart from it in keeping RoPE+RMSNorm (llama-style) where
+gpt-bigcode has learned positions and LayerNorm.
 """
 from repro.configs.base import ArchConfig
 

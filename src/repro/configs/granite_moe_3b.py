@@ -1,7 +1,7 @@
 """granite-moe-3b-a800m — MoE 40 experts top-8, d_ff/expert=512.
 
-[hf:ibm-granite/granite-3.0-*; spec field "MoE 40e top-8" followed — see DESIGN.md]
-40 experts are padded to 48 for expert-parallel sharding over 16 model shards.
+[hf:ibm-granite/granite-3.0-*; the assignment's "MoE 40e top-8" is followed as
+given.] Under ``moe_impl="ep"`` 40 experts pad to 48 over 16 model shards.
 """
 from repro.configs.base import ArchConfig
 

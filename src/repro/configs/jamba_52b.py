@@ -1,6 +1,6 @@
 """jamba-v0.1-52b — hybrid Mamba+attention 1:7 interleave, MoE 16e top-2 every 2nd
-layer. Mamba sublayers use our SSD block with d_state=16 (Jamba v0.1 is Mamba-1;
-SSD is the TPU-efficient equivalent — noted in DESIGN.md). [arXiv:2403.19887; hf]
+layer. [arXiv:2403.19887; hf] Departure: Jamba v0.1's Mamba sublayers are
+Mamba-1; here they run the one SSM mixer there is, Mamba-2's SSD, at d_state=16.
 """
 from repro.configs.base import ArchConfig
 

@@ -1,8 +1,9 @@
 """seamless-m4t-large-v2 — encoder-decoder backbone, audio frontend STUB.
 
 Backbone only per the brief: 24 encoder + 24 decoder layers, d=1024, 16H MHA,
-d_ff=8192, vocab 256206. ``input_specs`` supplies precomputed frame embeddings
-(B, S, d_model) for the encoder. [arXiv:2308.11596; hf]
+d_ff=8192, vocab 256206. There is no audio frontend: ``input_specs`` supplies
+precomputed frame embeddings (B, S, d_model) for the encoder in its place.
+[arXiv:2308.11596; hf]
 """
 from repro.configs.base import ArchConfig
 
@@ -21,7 +22,6 @@ CONFIG = ArchConfig(
     pos_emb="sinusoidal",
     encdec=True,
     n_enc_layers=24,
-    frontend="audio",
 )
 
 SMOKE = CONFIG.replace(

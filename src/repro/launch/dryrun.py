@@ -26,6 +26,7 @@ from repro.core.hlo_analysis import analyze_compiled
 from repro.distributed.sharding import make_rules
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import StepBuilder
+from repro.models.moe import IMPLS
 
 
 # --- optimization knobs for the §Perf hillclimb (all default-off) -----------
@@ -35,7 +36,7 @@ from repro.launch.steps import StepBuilder
 #                    (unrolled loop; halves attention flops+bytes)
 # bf16_loss        : bf16 softmax-xent with f32 reductions (no f32 logits
 #                    materialization)
-# moe_dense        : force dense-gather MoE (vs EP shard_map)
+# moe_dense        : force the gather MoE (vs EP shard_map)
 KNOWN_OPTS = ("decode_tp_params", "causal_skip", "bf16_loss", "moe_dense")
 
 
@@ -43,9 +44,9 @@ def tune_cfg(cfg, shape, moe_impl: str | None = None, opts: tuple = ()):
     """Per-cell config adjustments (the dry-run knobs the perf loop turns)."""
     kw = {}
     if cfg.moe:
-        kw["moe_impl"] = moe_impl or ("ep" if shape.kind != "decode" else "dense")
+        kw["moe_impl"] = moe_impl or ("ep" if shape.kind != "decode" else "gather")
         if "moe_dense" in opts:
-            kw["moe_impl"] = "dense"
+            kw["moe_impl"] = "gather"
     if "causal_skip" in opts:
         kw["causal_block_skip"] = True
     if "bf16_loss" in opts:
@@ -313,7 +314,7 @@ def main():
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--both-meshes", action="store_true")
     ap.add_argument("--microbatches", type=int, default=8)
-    ap.add_argument("--moe-impl", choices=["ep", "dense", "gather"])
+    ap.add_argument("--moe-impl", choices=IMPLS)
     ap.add_argument("--no-probes", action="store_true",
                     help="compile-proof only (no cost probes); used for multi-pod")
     ap.add_argument("--opt", action="append", default=[], choices=list(KNOWN_OPTS),

@@ -156,7 +156,7 @@ class GenResult:
 
 
 def generate(arch: str, *, smoke: bool = True, batch: int = 4, prompt_len: int = 32,
-             gen_tokens: int = 16, seed: int = 0, greedy: bool = True) -> GenResult:
+             gen_tokens: int = 16, seed: int = 0) -> GenResult:
     """Greedy generation of ``gen_tokens`` for ``batch`` random prompts,
     through ``Lockstep``: the path the benchmark's LM cells run."""
     cfg = get_config(arch, smoke=smoke)

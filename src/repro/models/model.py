@@ -32,17 +32,17 @@ class Model:
         return boxed
 
     # ---- steps ----
-    def loss(self, params, batch, rules: ShardingRules, **kw):
-        return transformer.loss_fn(self.cfg, params, batch, rules, **kw)
+    def loss(self, params, batch, rules: ShardingRules):
+        return transformer.loss_fn(self.cfg, params, batch, rules)
 
-    def prefill(self, params, batch, rules: ShardingRules, **kw):
-        return transformer.forward_prefill(self.cfg, params, batch, rules, **kw)
+    def prefill(self, params, batch, rules: ShardingRules):
+        return transformer.forward_prefill(self.cfg, params, batch, rules)
 
-    def decode_step(self, params, cache, tokens, pos, rules: ShardingRules, **kw):
-        return transformer.decode_step(self.cfg, params, cache, tokens, pos, rules, **kw)
+    def decode_step(self, params, cache, tokens, pos, rules: ShardingRules):
+        return transformer.decode_step(self.cfg, params, cache, tokens, pos, rules)
 
-    def greedy_step(self, params, cache, tokens, pos, rules: ShardingRules, **kw):
-        return transformer.greedy_step(self.cfg, params, cache, tokens, pos, rules, **kw)
+    def greedy_step(self, params, cache, tokens, pos, rules: ShardingRules):
+        return transformer.greedy_step(self.cfg, params, cache, tokens, pos, rules)
 
     def cache_specs(self, batch: int, max_seq: int):
         return transformer.cache_specs(self.cfg, batch, max_seq)
@@ -55,6 +55,4 @@ class Model:
 
 
 def build_model(cfg: ArchConfig, ep_size: Optional[int] = None) -> Model:
-    if cfg.moe and cfg.moe_impl == "ep" and ep_size is None:
-        ep_size = 16  # production model-axis size; padded expert count depends on it
     return Model(cfg, ep_size)

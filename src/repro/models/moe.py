@@ -1,24 +1,23 @@
-"""Mixture-of-Experts FFN.
+"""Mixture-of-Experts FFN: the three implementations ``cfg.moe_impl`` names.
+``dispatch`` alone picks the one a layer runs: ``share`` in every mode;
+otherwise ``gather`` in decode, ``ep`` where a mesh is set, else ``gather``.
+``apply_moe`` runs the one it picked.
 
-Three dispatch implementations; the first two are FLOP-faithful (no dense
-all-experts compute):
-
-* ``gather`` — global sort-free grouped dispatch via cumsum-ranked scatter into an
-  (E, C) buffer. Exact when capacity suffices; used for decode (few tokens) and
-  smoke tests. GSPMD shards the expert einsum over the ``model`` axis.
+* ``gather`` (the default) — global sort-free grouped dispatch via cumsum-ranked
+  scatter into an (E, C) buffer; capacity-bounded, so it drops the tokens an
+  expert gets beyond C. GSPMD shards the expert einsum over the ``model`` axis.
 * ``ep`` — expert parallelism via ``shard_map``: tokens are split over the
   (pod·data) batch axes *and* the ``model`` axis (sequence split), routed locally,
   exchanged with ``all_to_all`` to the expert-owner shards, computed, and returned.
-  This is the production path for train/prefill and makes the MoE collective
+  This is the train/prefill path on a mesh and makes the MoE collective
   schedule (2× all_to_all + all_gather) explicit in the HLO.
-* ``share`` — one chip's expert share (``cfg.moe_impl == "share"``, in every
-  mode): the layer holds experts [a, a + n) of the ``n_experts`` its router
-  scores, routes over all of them, and computes only its own experts' part of
-  the result. Dropless: each held expert takes every token at its gate, which
-  is 0 where the router did not pick it, so no capacity bounds it. At decode
-  batch sizes that costs what grouping would (a token picks up to k of the
-  held experts); in prefill it computes held / (k x held / n_experts) times
-  the flops a grouped kernel would.
+* ``share`` — one chip's expert share: the layer holds experts [a, a + n) of the
+  ``n_experts`` its router scores, routes over all of them, and computes only its
+  own experts' part of the result. Dropless: each held expert takes every token
+  at its gate, which is 0 where the router did not pick it, so no capacity bounds
+  it. At decode batch sizes that costs what grouping would (a token picks up to k
+  of the held experts); in prefill it computes held / (k x held / n_experts)
+  times the flops a grouped kernel would.
 
 Experts are padded to a multiple of the model-axis size when necessary
 (granite-moe: 40 -> 48); the router never selects padded experts. A shared
@@ -40,9 +39,25 @@ from repro.models import layers
 
 F32 = jnp.float32
 
+IMPLS = ("gather", "ep", "share")
+EP_SIZE = 16  # production model-axis size: "ep" pads to it when no mesh says
+
+
+def dispatch(cfg: ArchConfig, decode: bool, mesh: bool) -> str:
+    """The implementation a layer of ``cfg`` runs, in a decode step or not,
+    with a mesh set or not."""
+    impl = cfg.moe_impl
+    if impl not in IMPLS:
+        raise ValueError(f"moe_impl {impl!r}; known: {IMPLS}")
+    if impl == "share" or (impl == "ep" and mesh and not decode):
+        return impl
+    return "gather"
+
 
 def padded_experts(cfg: ArchConfig, ep_size: Optional[int]) -> int:
     e = cfg.n_experts
+    if ep_size is None and cfg.moe_impl == "ep":
+        ep_size = EP_SIZE
     if ep_size and e % ep_size != 0:
         e = math.ceil(e / ep_size) * ep_size
     return e
@@ -135,7 +150,7 @@ def _group(token_e, token_w, T: int, E: int, C: int):
             w_of_slot[: E * C].reshape(E, C))
 
 
-def _moe_gather(cfg: ArchConfig, p, x, rules: ShardingRules, capacity_mult: float = 1.0):
+def _moe_gather(cfg: ArchConfig, p, x, rules: ShardingRules):
     """Global grouped dispatch (no shard_map). x: (B, S, d)."""
     B, S, d = x.shape
     T = B * S
@@ -144,7 +159,7 @@ def _moe_gather(cfg: ArchConfig, p, x, rules: ShardingRules, capacity_mult: floa
     top_i, top_w, aux = _route(cfg, logits)
     E = p["w_up"].shape[0]                                              # padded
     k = cfg.n_experts_per_tok
-    C = max(1, int(math.ceil(T * k / cfg.n_experts * cfg.capacity_factor * capacity_mult)))
+    C = max(1, int(math.ceil(T * k / cfg.n_experts * cfg.capacity_factor)))
     C = min(C, T)
     idx, w = _group(top_i.reshape(-1), top_w.reshape(-1), T, E, C)
     x_pad = jnp.concatenate([xf, jnp.zeros((1, d), xf.dtype)], axis=0)
@@ -253,15 +268,14 @@ def _moe_share(cfg: ArchConfig, p, x, rules: ShardingRules):
 def apply_moe(cfg: ArchConfig, p, x, rules: ShardingRules, impl: Optional[str] = None):
     """Returns (y, aux, held): the layer's output with the shared expert's
     added, the load-balancing loss, and the (token, expert) assignments that
-    went to experts held here (all of them, but for an expert share)."""
-    if cfg.moe_impl == "share":
+    went to experts held here (all of them, but for an expert share).
+    ``impl`` is what ``dispatch`` gave for the step; by default, for one
+    that does not decode."""
+    impl = impl or dispatch(cfg, False, rules.mesh is not None)
+    if impl == "share":
         y, aux, held = _moe_share(cfg, p, x, rules)
     else:
-        impl = impl or cfg.moe_impl
-        if impl == "ep" and rules.mesh is not None:
-            y, aux = _moe_ep(cfg, p, x, rules)
-        else:
-            y, aux = _moe_gather(cfg, p, x, rules)
+        y, aux = (_moe_ep if impl == "ep" else _moe_gather)(cfg, p, x, rules)
         held = jnp.int32(x.shape[0] * x.shape[1] * cfg.n_experts_per_tok)
     if "shared" in p:
         y = y + layers.apply_mlp(cfg, p["shared"], x, rules)

@@ -125,7 +125,7 @@ def init_lm(cfg: ArchConfig, key, ep_size: Optional[int] = None):
 
 def _apply_block_pos(cfg: ArchConfig, entry: dict, p, x, rules: ShardingRules, *,
                      mode: str, positions, cache_entry=None, pos=None,
-                     enc_out=None, moe_impl=None, q_chunk=1024):
+                     enc_out=None):
     """One sublayer-group. mode: 'train' | 'prefill' | 'decode'.
     Returns (x, new_cache_entry, stats): stats as ``_no_stats`` gives them."""
     stats = _no_stats()
@@ -141,7 +141,7 @@ def _apply_block_pos(cfg: ArchConfig, entry: dict, p, x, rules: ShardingRules, *
             attn_mode = ("bidir" if (cfg.encdec and enc_out is None
                                      and not entry["cross"]) else "causal")
             out, nc = layers.attention(cfg, p["mixer"], h, rules, mode=attn_mode,
-                                       positions=positions, q_chunk=q_chunk)
+                                       positions=positions)
             if mode == "prefill" and nc is not None:
                 new_cache["attn"] = nc
     else:
@@ -175,7 +175,7 @@ def _apply_block_pos(cfg: ArchConfig, entry: dict, p, x, rules: ShardingRules, *
         if entry["ffn"] == "dense":
             out = layers.apply_mlp(cfg, p["ffn"], h, rules)
         else:
-            impl = moe_impl or ("gather" if mode == "decode" else cfg.moe_impl)
+            impl = moe.dispatch(cfg, mode == "decode", rules.mesh is not None)
             out, aux, held = moe.apply_moe(cfg, p["ffn"], h, rules, impl=impl)
             stats = {"moe_aux": aux, "moe_held": held}
         x = x + _residual(cfg, out)
@@ -202,7 +202,7 @@ def _residual(cfg: ArchConfig, out):
 
 def _run_stack(cfg: ArchConfig, blocks, x, rules: ShardingRules, *, mode: str,
                positions, prog, cache=None, pos=None, enc_out=None,
-               moe_impl=None, q_chunk=1024, remat: bool = False):
+               remat: bool = False):
     """Scan the stacked blocks. Returns (x, new_cache_or_None, stats summed
     over the layers)."""
 
@@ -218,8 +218,7 @@ def _run_stack(cfg: ArchConfig, blocks, x, rules: ShardingRules, *, mode: str,
             ce = cache_in[j] if cache_in is not None else None
             xc, nc, stats = _apply_block_pos(
                 cfg, entry, layer_ps[j], xc, rules, mode=mode, positions=positions,
-                cache_entry=ce, pos=pos, enc_out=enc_out, moe_impl=moe_impl,
-                q_chunk=q_chunk)
+                cache_entry=ce, pos=pos, enc_out=enc_out)
             new_caches.append(nc)
             total = jax.tree.map(jnp.add, total, stats)
         out_ys = (tuple(new_caches), total) if mode != "train" else total
@@ -230,7 +229,7 @@ def _run_stack(cfg: ArchConfig, blocks, x, rules: ShardingRules, *, mode: str,
             body, policy=jax.checkpoint_policies.nothing_saveable)
 
     xs = (blocks, cache) if cache is not None else blocks
-    if cfg.unroll or not cfg.scan_layers:
+    if cfg.unroll:
         n_stack = jax.tree.leaves(blocks)[0].shape[0]
         ys_list = []
         for i in range(n_stack):
@@ -249,30 +248,24 @@ def _run_stack(cfg: ArchConfig, blocks, x, rules: ShardingRules, *, mode: str,
 # public model functions
 # ---------------------------------------------------------------------------
 
-def _encode(cfg: ArchConfig, params, batch, rules: ShardingRules, q_chunk=1024,
-            remat=False):
-    if "frames" in batch:
-        x = batch["frames"].astype(jnp.dtype(cfg.dtype))
-    else:
-        x = layers.embed_tokens(cfg, params["embed"], batch["src_tokens"], rules)
+def _encode(cfg: ArchConfig, params, batch, rules: ShardingRules, remat=False):
+    x = batch["frames"].astype(jnp.dtype(cfg.dtype))
     S = x.shape[1]
     positions = jnp.arange(S)
     if cfg.pos_emb == "sinusoidal":
         x = x + layers.sinusoidal_pos_emb(positions, cfg.d_model, x.dtype)[None]
     enc_prog = block_program(cfg, decoder=False)
     x, _, _ = _run_stack(cfg, params["enc_blocks"], x, rules, mode="train",
-                         positions=positions, prog=enc_prog, q_chunk=q_chunk,
-                         remat=remat)
+                         positions=positions, prog=enc_prog, remat=remat)
     return layers.apply_norm(cfg, params["enc_norm"], x)
 
 
-def forward_train(cfg: ArchConfig, params, batch, rules: ShardingRules,
-                  moe_impl=None, q_chunk=1024):
-    """Returns (logits, aux). batch: {tokens, [frames|src_tokens]}."""
+def forward_train(cfg: ArchConfig, params, batch, rules: ShardingRules):
+    """Returns (logits, aux). batch: {tokens, [frames]}."""
     remat = cfg.remat == "full"
     enc_out = None
     if cfg.encdec:
-        enc_out = _encode(cfg, params, batch, rules, q_chunk, remat)
+        enc_out = _encode(cfg, params, batch, rules, remat)
     tokens = batch["tokens"]
     x = layers.embed_tokens(cfg, params["embed"], tokens, rules)
     S = x.shape[1]
@@ -282,16 +275,15 @@ def forward_train(cfg: ArchConfig, params, batch, rules: ShardingRules,
     prog = block_program(cfg)
     x, _, stats = _run_stack(cfg, params["blocks"], x, rules, mode="train",
                              positions=positions, prog=prog, enc_out=enc_out,
-                             moe_impl=moe_impl, q_chunk=q_chunk, remat=remat)
+                             remat=remat)
     x = layers.apply_norm(cfg, params["final_norm"], x)
     logits = layers.unembed(cfg, params["embed"], x, rules)
     return logits, stats["moe_aux"]
 
 
 def loss_fn(cfg: ArchConfig, params, batch, rules: ShardingRules,
-            moe_impl=None, q_chunk=1024, z_loss: float = 1e-4,
-            moe_aux_weight: float = 1e-2):
-    logits, aux = forward_train(cfg, params, batch, rules, moe_impl, q_chunk)
+            z_loss: float = 1e-4, moe_aux_weight: float = 1e-2):
+    logits, aux = forward_train(cfg, params, batch, rules)
     targets = batch["targets"]
     if jnp.dtype(cfg.softmax_dtype) == jnp.float32:
         lf = logits.astype(F32)
@@ -311,12 +303,11 @@ def loss_fn(cfg: ArchConfig, params, batch, rules: ShardingRules,
     return total, {"nll": nll, "z_loss": zl, "moe_aux": aux}
 
 
-def forward_prefill(cfg: ArchConfig, params, batch, rules: ShardingRules,
-                    moe_impl=None, q_chunk=1024):
+def forward_prefill(cfg: ArchConfig, params, batch, rules: ShardingRules):
     """Returns (cache, last_token_logits)."""
     enc_out = None
     if cfg.encdec:
-        enc_out = _encode(cfg, params, batch, rules, q_chunk)
+        enc_out = _encode(cfg, params, batch, rules)
     tokens = batch["tokens"]
     x = layers.embed_tokens(cfg, params["embed"], tokens, rules)
     S = x.shape[1]
@@ -326,45 +317,40 @@ def forward_prefill(cfg: ArchConfig, params, batch, rules: ShardingRules,
     prog = block_program(cfg)
     # cache entries are produced by the scan (ys): inject a dummy cache=None path
     x, new_cache, _ = _run_stack(cfg, params["blocks"], x, rules, mode="prefill",
-                                 positions=positions, prog=prog, enc_out=enc_out,
-                                 moe_impl=moe_impl, q_chunk=q_chunk)
+                                 positions=positions, prog=prog, enc_out=enc_out)
     x = layers.apply_norm(cfg, params["final_norm"], x)
     logits = layers.unembed(cfg, params["embed"], x[:, -1:, :], rules)
     return new_cache, logits
 
 
-def _decode(cfg: ArchConfig, params, cache, tokens, pos, rules, moe_impl):
+def _decode(cfg: ArchConfig, params, cache, tokens, pos, rules):
     x = layers.embed_tokens(cfg, params["embed"], tokens, rules)
     if cfg.pos_emb == "sinusoidal":
         pe = layers.sinusoidal_pos_emb(jnp.asarray(pos)[None], cfg.d_model, x.dtype)
         x = x + pe[None]
     prog = block_program(cfg)
     x, new_cache, stats = _run_stack(cfg, params["blocks"], x, rules, mode="decode",
-                                     positions=None, prog=prog, cache=cache, pos=pos,
-                                     moe_impl=moe_impl)
+                                     positions=None, prog=prog, cache=cache, pos=pos)
     x = layers.apply_norm(cfg, params["final_norm"], x)
     logits = layers.unembed(cfg, params["embed"], x, rules)
     return new_cache, logits, stats
 
 
-def decode_step(cfg: ArchConfig, params, cache, tokens, pos, rules: ShardingRules,
-                moe_impl="gather"):
+def decode_step(cfg: ArchConfig, params, cache, tokens, pos, rules: ShardingRules):
     """One decode step. tokens: (B, 1); pos: scalar absolute position.
     Returns (new_cache, logits (B, 1, V))."""
-    new_cache, logits, _ = _decode(cfg, params, cache, tokens, pos, rules, moe_impl)
+    new_cache, logits, _ = _decode(cfg, params, cache, tokens, pos, rules)
     return new_cache, logits
 
 
-def greedy_step(cfg: ArchConfig, params, cache, tokens, pos, rules: ShardingRules,
-                moe_impl="gather"):
+def greedy_step(cfg: ArchConfig, params, cache, tokens, pos, rules: ShardingRules):
     """One decode step that also picks each sequence's next token on the device.
 
     Returns (new_cache, logits (B, 1, V), next_tokens (B, 1) int32, report):
     ``report`` is int32 (B + 1,), the next tokens and then the step's
     (token, expert) assignments to experts held here, so that one copy to
     the host brings both (``split_report``)."""
-    new_cache, logits, stats = _decode(cfg, params, cache, tokens, pos, rules,
-                                       moe_impl)
+    new_cache, logits, stats = _decode(cfg, params, cache, tokens, pos, rules)
     nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
     report = jnp.concatenate([nxt, stats["moe_held"][None]])
     return new_cache, logits, nxt[:, None], report

@@ -120,7 +120,7 @@ def test_skewed_router_drops_nothing():
     assert int(held) == x.shape[1] * k
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-4, atol=1e-5)
     shared = layers.apply_mlp(cfg, p["shared"], x, RULES)
-    gathered, _, _ = moe.apply_moe(cfg.replace(moe_impl="dense"), p, x, RULES)
+    gathered, _, _ = moe.apply_moe(cfg.replace(moe_impl="gather"), p, x, RULES)
     assert _rel_rms(gathered - shared, want - shared) > 0.5
 
 

@@ -1,5 +1,6 @@
-"""MoE dispatch: gather path exactness, EP shard_map path equivalence (8 fake
-devices, subprocess), capacity/dropping semantics."""
+"""MoE dispatch: the rule that picks an implementation, gather path
+exactness, EP shard_map path equivalence (8 fake devices, subprocess),
+capacity/dropping semantics."""
 import os
 import subprocess
 import sys
@@ -71,6 +72,36 @@ def test_expert_padding():
     pv = unbox_values(p)
     assert pv["w_up"].shape[0] == 12
     assert pv["router"].shape[1] == 10       # router never selects pads
+
+
+# (moe_impl, decode, mesh) -> the implementation the layer runs
+DISPATCH = {
+    ("gather", False, False): "gather", ("gather", False, True): "gather",
+    ("gather", True, False): "gather", ("gather", True, True): "gather",
+    ("ep", False, False): "gather", ("ep", False, True): "ep",
+    ("ep", True, False): "gather", ("ep", True, True): "gather",
+    ("share", False, False): "share", ("share", False, True): "share",
+    ("share", True, False): "share", ("share", True, True): "share",
+}
+
+
+@pytest.mark.parametrize("impl,decode,mesh", sorted(DISPATCH))
+def test_dispatch_rule(impl, decode, mesh):
+    cfg = get_config("olmoe-1b-7b", smoke=True).replace(moe_impl=impl)
+    assert moe.dispatch(cfg, decode, mesh) == DISPATCH[impl, decode, mesh]
+
+
+def test_dispatch_refuses_unknown_impl():
+    cfg = get_config("olmoe-1b-7b", smoke=True).replace(moe_impl="dense")
+    with pytest.raises(ValueError, match="dense"):
+        moe.dispatch(cfg, False, False)
+
+
+def test_ep_pads_to_production_axis_without_a_mesh():
+    cfg = get_config("granite-moe-3b-a800m", smoke=True).replace(n_experts=40)
+    assert moe.padded_experts(cfg.replace(moe_impl="ep"), None) == 48
+    assert moe.padded_experts(cfg.replace(moe_impl="ep"), 8) == 40
+    assert moe.padded_experts(cfg, None) == 40
 
 
 EP_SNIPPET = """
